@@ -27,26 +27,13 @@ from jax import lax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: the one helper every mesh program
-    build goes through (``transport.tpu_mesh`` — and via it
-    ``transport.multihost``'s pod transports).
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; the JAX this
-    container ships only has ``jax.experimental.shard_map.shard_map``
-    whose equivalent knob is ``check_rep=``. Before this shim, every
-    mesh/multiprocess test and the multichip dryrun's ``mesh_build``
-    phase died on the ``jax.shard_map`` AttributeError (the 48
-    seed-era environment failures the PR-6 blackbox journal pinned)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """The one ``jax.shard_map`` call every mesh program build goes
+    through (``transport.tpu_mesh`` and, via it, ``transport.multihost``
+    and ``transport.group_mesh``), so the keyword set lives in one
+    place."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 

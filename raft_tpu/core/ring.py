@@ -34,10 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# CI hook (VERDICT r3 #2): route kernel-eligible shapes through the Pallas
+# CI hook: route kernel-eligible shapes through the Pallas
 # path in INTERPRET mode on non-TPU backends, so the kernel's composition
 # with shard_map mesh programs is exercised before real multi-chip hardware
-# runs it. Enabled per-process by env (survives the dryrun re-exec) or
+# runs it. Enabled per-process by env (RAFT_TPU_PALLAS_INTERPRET) or
 # per-test by force_pallas_interpret().
 _force_interpret = bool(os.environ.get("RAFT_TPU_PALLAS_INTERPRET"))
 

@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.ring import pallas_interpret
 from raft_tpu.ec import gf
 from raft_tpu.ec.rs import RSCode
 
@@ -64,7 +64,7 @@ def _mul_const_bits(x: jax.Array, consts_rc: np.ndarray) -> jax.Array:
 
 
 def _parity_kernel(consts: np.ndarray, data_ref, out_ref):
-    """data_ref: u8[k, B, Sk] -> out_ref: u8[m, B, Sk] (VMEM resident)."""
+    """data_ref: u8[k, bb, Sk] -> out_ref: u8[m, bb, Sk] (one row block)."""
     m, k, _ = consts.shape
     for p in range(m):
         acc = jnp.zeros_like(data_ref[0])
@@ -73,26 +73,49 @@ def _parity_kernel(consts: np.ndarray, data_ref, out_ref):
         out_ref[p] = acc
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def _parity_pallas(k: int, m: int, consts_key, data_sliced: jax.Array) -> jax.Array:
+def _row_block(B: int) -> int:
+    """Entry rows per grid step: the largest power-of-two block of at
+    most 1024 rows (a multiple of the u8 sublane tile, 32) dividing
+    ``B``, else the whole batch. Tiling over rows keeps VMEM use at one
+    block whatever the window length — a whole ring lap (2^17 x 264 B)
+    does not fit VMEM in one piece."""
+    for bb in (1024, 512, 256, 128, 64, 32):
+        if B % bb == 0:
+            return bb
+    return B
+
+
+def _interpret(interpret) -> bool:
+    return pallas_interpret() if interpret is None else bool(interpret)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 4))
+def _parity_pallas(k: int, m: int, consts_key, data_sliced: jax.Array,
+                   interpret: bool) -> jax.Array:
     """u8[k, B, Sk] data shards -> u8[m, B, Sk] parity shards."""
     consts = np.frombuffer(consts_key, np.uint8).reshape(m, k, 8)
     B, Sk = data_sliced.shape[1], data_sliced.shape[2]
+    bb = _row_block(B)
     return pl.pallas_call(
         partial(_parity_kernel, consts),
         out_shape=jax.ShapeDtypeStruct((m, B, Sk), jnp.uint8),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=jax.devices()[0].platform == "cpu",
+        grid=(B // bb,),
+        in_specs=[pl.BlockSpec((k, bb, Sk), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((m, bb, Sk), lambda i: (0, i, 0)),
+        interpret=interpret,
     )(data_sliced)
 
 
-def encode_pallas(code: RSCode, data: jax.Array) -> jax.Array:
+def encode_pallas(code: RSCode, data: jax.Array,
+                  interpret: bool | None = None) -> jax.Array:
     """u8[B, S] entries -> u8[n, B, S/k] shard rows; parity on the TPU
-    kernel, data rows by (free) byte-slicing."""
+    kernel, data rows by (free) byte-slicing. ``interpret`` defaults to
+    ``core.ring.pallas_interpret()``."""
     B, S = data.shape
     d = jnp.moveaxis(data.reshape(B, code.k, S // code.k), 1, 0)
-    parity = _parity_pallas(code.k, code.m, _parity_consts_key(code.n, code.k), d)
+    parity = _parity_pallas(code.k, code.m,
+                            _parity_consts_key(code.n, code.k), d,
+                            _interpret(interpret))
     return jnp.concatenate([d, parity])
 
 
@@ -137,8 +160,8 @@ def encode_device(code: RSCode, data: jax.Array) -> jax.Array:
     XLA formulation elsewhere (CPU tests / interpret). This is the
     production encode the engine's EC tick calls — the north star names the
     Pallas RS encode as the TPU data path, so TPU must actually run it."""
-    if jax.devices()[0].platform == "tpu":
-        return encode_pallas(code, data)
+    if not pallas_interpret():
+        return encode_pallas(code, data, interpret=False)
     return encode_bitwise_xla(code, data)
 
 
@@ -157,8 +180,9 @@ def _parity_cols_kernel(consts, sk: int, data_ref, out_ref):
         out_ref[:, p * sk:(p + 1) * sk] = acc
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def _encode_fold_pallas(k: int, m: int, consts_key, data: jax.Array) -> jax.Array:
+@partial(jax.jit, static_argnums=(0, 1, 2, 4))
+def _encode_fold_pallas(k: int, m: int, consts_key, data: jax.Array,
+                        interpret: bool) -> jax.Array:
     """u8[B, S] entries -> i32[B, (k+m)*Wk] FOLDED shard layout in one pass.
 
     The folded layout's data blocks are byte-identical to the input (the
@@ -169,12 +193,14 @@ def _encode_fold_pallas(k: int, m: int, consts_key, data: jax.Array) -> jax.Arra
     consts = np.frombuffer(consts_key, np.uint8).reshape(m, k, 8)
     B, S = data.shape
     sk = S // k
+    bb = _row_block(B)
     parity = pl.pallas_call(
         partial(_parity_cols_kernel, consts, sk),
         out_shape=jax.ShapeDtypeStruct((B, m * sk), jnp.uint8),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=jax.devices()[0].platform == "cpu",
+        grid=(B // bb,),
+        in_specs=[pl.BlockSpec((bb, S), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bb, m * sk), lambda i: (i, 0)),
+        interpret=interpret,
     )(data)
 
     def to_words(x):
@@ -214,9 +240,9 @@ def encode_fold_device(code: RSCode, data: jax.Array) -> jax.Array:
     payload layout). Equals ``fold_shards_device(encode_device(...))``
     exactly (asserted in tests); on TPU it skips the shard-major
     round-trip copies."""
-    if jax.devices()[0].platform == "tpu":
+    if not pallas_interpret():
         return _encode_fold_pallas(
-            code.k, code.m, _parity_consts_key(code.n, code.k), data
+            code.k, code.m, _parity_consts_key(code.n, code.k), data, False
         )
     return fold_shards_device(encode_device(code, data))
 
@@ -237,12 +263,14 @@ def _decode_consts_key(n: int, k: int, rows: tuple) -> bytes:
     return _bit_consts(RSCode(n, k).decode_matrix(list(rows))).tobytes()
 
 
-def decode_pallas(code: RSCode, shards: jax.Array, rows) -> jax.Array:
+def decode_pallas(code: RSCode, shards: jax.Array, rows,
+                  interpret: bool | None = None) -> jax.Array:
     """u8[k, B, Sk] shards from ``rows`` -> u8[B, S] decoded entries, on
-    the same VMEM-resident bit-sliced kernel as the parity encode."""
+    the same bit-sliced kernel as the parity encode."""
     rows = tuple(int(r) for r in rows)
     out = _parity_pallas(
-        code.k, code.k, _decode_consts_key(code.n, code.k, rows), shards
+        code.k, code.k, _decode_consts_key(code.n, code.k, rows), shards,
+        _interpret(interpret),
     )                                                   # [k, B, Sk]
     b, sk = out.shape[1], out.shape[2]
     return jnp.moveaxis(out, 0, 1).reshape(b, code.k * sk)
@@ -260,6 +288,6 @@ def decode_bitwise_xla(code: RSCode, shards: jax.Array, rows) -> jax.Array:
 
 def decode_device(code: RSCode, shards: jax.Array, rows) -> jax.Array:
     """Platform-dispatched decode (mirrors ``encode_device``)."""
-    if jax.devices()[0].platform == "tpu":
-        return decode_pallas(code, shards, rows)
+    if not pallas_interpret():
+        return decode_pallas(code, shards, rows, interpret=False)
     return decode_bitwise_xla(code, shards, rows)

@@ -2,7 +2,9 @@
 inside this package so installed copies keep the native fast path).
 
 The library is built lazily with g++ on first use and cached next to the
-source; every entry point degrades to the NumPy oracle when the toolchain
+source under a name keyed on the source's SHA-256, so only a build of the
+``rs_codec.cpp`` beside it can ever load (a stale build of other source
+bytes carries another name and is ignored); every entry point degrades to the NumPy oracle when the toolchain
 or the .so is unavailable, so the framework never *requires* the native
 path — it is the fast host data plane, not a correctness dependency.
 """
@@ -10,6 +12,7 @@ path — it is the fast host data plane, not a correctness dependency.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,7 +22,9 @@ from typing import Optional
 import numpy as np
 
 _SRC = Path(__file__).resolve().parent / "rs_codec.cpp"
-_LIB = _SRC.with_suffix(".so")
+_LIB = _SRC.with_name(
+    f"rs_codec.{hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]}.so"
+)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -52,9 +57,8 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _SRC.exists() or not _build():
-                return None
+        if not _LIB.exists() and not _build():
+            return None
         try:
             lib = ctypes.CDLL(str(_LIB))
         except OSError:

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import os
 import threading
 import time
 from collections import deque
@@ -85,6 +86,32 @@ DEFAULT_HOT_PATHS = (
 )
 
 UNLABELED = "(unlabeled)"
+
+#: Where the persistent XLA compilation cache lives when the environment
+#: does not place it: one fixed path inside the checkout (gitignored).
+#: The path is part of the cache key, so it never depends on a temp
+#: name, a pid or the time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a program entry
+    point (``chip_smoke.py``, ``bench.py``, ``northstar.py``) and return
+    its directory. With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already
+    reads it and no path is set here; otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return path
 
 # ---------------------------------------------------------------- plumbing
 #: active watches. The hot-path contract hangs on this list: labeled()

@@ -53,8 +53,8 @@ import time
 from typing import Dict, Optional, Sequence, Tuple
 
 #: µs-to-100ms log-spaced buckets: host phases live in the 1 µs - 1 ms
-#: band on a local backend and the 10-100 ms band through a dispatch
-#: tunnel; the default registry buckets (0.5s+) would flatten both.
+#: band, device waits and compiles reach into the 10-100 ms band; the
+#: default registry buckets (0.5s+) would flatten both.
 HOST_PHASE_BUCKETS = (
     1e-6, 3e-6, 1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 1e-1,
 )

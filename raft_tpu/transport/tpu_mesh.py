@@ -190,7 +190,7 @@ class TpuMeshTransport:
         # fused-dispatch program family (built lazily): same protocol
         # functions with the engine's term_floor threaded through, which
         # lets core.step route to the per-device fused kernels
-        # (core.step_mesh) when the shape allows — VERDICT r4 #1: the
+        # (core.step_mesh) when the shape allows: the
         # deployment shape and the fast shape are the same program now.
         self._comm = comm
         self._state_specs = state_specs

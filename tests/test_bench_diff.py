@@ -74,12 +74,19 @@ class TestLoadBench:
         with pytest.raises(ValueError):
             load_bench(str(p))
 
-    def test_real_repo_artifact_loads(self):
-        from pathlib import Path
-
-        artifact = Path(__file__).resolve().parent.parent / "BENCH_r04.json"
-        legs = load_bench(str(artifact))
-        assert "c2_batched" in legs and "p50_us" in legs["c2_batched"]
+    def test_wrapper_with_parsed_combined_object_loads(self, tmp_path):
+        """The driver-style wrapper whose ``parsed`` holds the combined
+        final object: legs come from its ``configs``."""
+        p = tmp_path / "bench_wrapped.json"
+        combined = {"metric": "commit_p50_latency", "value": 2.0,
+                    "configs": {"c2_batched": {"p50_us": 2.0,
+                                               "method": "device"}}}
+        p.write_text(json.dumps({
+            "n": 4, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(combined), "parsed": combined,
+        }))
+        legs = load_bench(str(p))
+        assert legs["c2_batched"]["p50_us"] == 2.0
 
 
 class TestCompare:
@@ -240,8 +247,8 @@ class TestCompileMemoryColumns:
         assert reg == []
 
     def test_old_artifact_without_columns_does_not_gate(self):
-        """BENCH_r01..r05 predate the columns: their absence must read
-        as "not measured", never as a regression."""
+        """Artifacts older than the columns: their absence must read as
+        "not measured", never as a regression."""
         old = {"steady": {"p50_us": 2.0}}
         new = {"steady": {"p50_us": 2.0, "compile_count": 7,
                           "mem_high_water_bytes": 123456}}

@@ -219,7 +219,8 @@ class TestKernels:
         data = rng.integers(0, 256, (16, 32 * k), dtype=np.uint8)
         want = np.asarray(fold_shards_device(encode_device(code, jnp.asarray(data))))
         got = np.asarray(_encode_fold_pallas(
-            code.k, code.m, _parity_consts_key(n, k), jnp.asarray(data)
+            code.k, code.m, _parity_consts_key(n, k), jnp.asarray(data),
+            True,
         ))
         np.testing.assert_array_equal(got, want)
 

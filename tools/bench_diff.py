@@ -1,10 +1,9 @@
 """Bench regression gate: diff two bench JSON artifacts leg by leg.
 
 ``bench.py`` has emitted per-leg JSON rows plus a final combined object
-since round 2, and the repo keeps the per-round artifacts
-(BENCH_r01..r05) — but nothing ever READ them, so the perf trajectory
-was write-only: a regression surfaced only when a human eyeballed two
-files. This module closes the loop:
+since round 2, but nothing ever READ two runs side by side, so the
+perf trajectory was write-only: a regression surfaced only when a human
+eyeballed two files. This module closes the loop:
 
     python tools/bench_diff.py OLD.json NEW.json [--threshold 0.10]
     python bench.py --compare OLD.json [--regress-threshold 0.10]
@@ -15,7 +14,7 @@ metric regressed past the threshold (fractional: 0.10 = 10%).
 Accepted artifact shapes (auto-detected):
 - raw ``bench.py`` stdout: one JSON object per line, final line the
   combined object (``configs`` maps leg name -> row);
-- the repo's BENCH_rNN wrapper: ``{"cmd", "rc", "tail", "parsed"}`` —
+- a run wrapper: ``{"cmd", "rc", "tail", "parsed"}`` —
   ``parsed`` when present, else the combined/leg lines inside ``tail``
   (a deadline- or rc=124-killed run still yields its finished legs);
 - a bare combined object.
@@ -186,7 +185,7 @@ def load_bench(path: str) -> Dict[str, dict]:
     except json.JSONDecodeError:
         doc = None
     if isinstance(doc, dict) and "tail" in doc and "cmd" in doc:
-        # BENCH_rNN wrapper: prefer the parsed combined object, fall
+        # run wrapper: prefer the parsed combined object, fall
         # back to the JSON lines inside the captured stdout tail
         if isinstance(doc.get("parsed"), dict):
             return _flatten_legs(doc["parsed"])
@@ -316,7 +315,7 @@ def main(argv=None) -> int:
         description="diff two bench.py JSON artifacts leg by leg; "
                     "non-zero exit on regression past the threshold",
     )
-    ap.add_argument("old", help="baseline artifact (e.g. BENCH_r04.json)")
+    ap.add_argument("old", help="baseline artifact (an earlier run)")
     ap.add_argument("new", help="candidate artifact")
     ap.add_argument("--threshold", type=float, default=0.10,
                     help="fractional regression gate (default 0.10)")
