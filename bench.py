@@ -58,7 +58,7 @@ from raft_tpu.core.gates import pipeline_lap_gate, ring_kernel_gate
 from raft_tpu.core.state import init_state
 from raft_tpu.core.step import replicate_step
 from raft_tpu.obs.compile import use_persistent_cache
-from raft_tpu.obs.profiling import device_seconds, op_breakdown
+from raft_tpu.obs.profiling import device_seconds
 from raft_tpu.obs.registry import MetricsRegistry
 
 REFERENCE_TICK_US = 2_000_000.0  # main.go:394 — 2 s replication tick
@@ -291,32 +291,13 @@ def bench_scan(cfg: RaftConfig, fn, reps: int = REPS) -> dict:
         device_seconds(fn, lambda: (init_state(cfg),)) * 1e6 / T_STEPS
         for _ in range(reps)
     ]
-    method = "device"
-    # one extra traced rep into a KEPT trace dir so the row carries
-    # per-kernel device-time attribution (obs.profiling.op_breakdown)
-    import shutil
-    import tempfile
-
-    tdir = tempfile.mkdtemp(prefix="raft_tpu_bench_trace_")
-    try:
-        device_seconds(fn, lambda: (init_state(cfg),), warmups=0,
-                       trace_dir=tdir)
-        breakdown = [
-            {"op": nm, "calls": c, "total_ms": round(ms, 3)}
-            for nm, c, ms in op_breakdown(tdir, top=8)
-        ] or None
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
     p50, p99 = _percentiles(per_step)
-    row = {
+    return {
         "p50_us": round(p50, 3),
         "p99_us": round(p99, 3),
         "entries_per_sec": round(cfg.batch_size / p50 * 1e6, 1),
-        "method": method,
+        "method": "device",
     }
-    if breakdown is not None:
-        row["op_breakdown"] = breakdown
-    return row
 
 
 def _best_program(steady: dict, repair_capable: dict) -> dict:

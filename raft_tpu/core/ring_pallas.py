@@ -193,6 +193,7 @@ def write_window_both_tpu(buf_p: jax.Array, buf_t: jax.Array,
     )
     return pl.pallas_call(
         functools.partial(_write_both_kernel, BR, C),
+        name="ring_write_both",
         out_shape=[
             jax.ShapeDtypeStruct((C, M), buf_p.dtype),
             jax.ShapeDtypeStruct((L, C), buf_t.dtype),
@@ -240,6 +241,7 @@ def write_window_cols_tpu(buf: jax.Array, win: jax.Array, s: jax.Array,
     )
     return pl.pallas_call(
         functools.partial(_write_kernel, BR, C),
+        name="ring_write",
         out_shape=jax.ShapeDtypeStruct((C, M), buf.dtype),
         grid_spec=grid_spec,
         input_output_aliases={3: 0},      # buf (after 1 scalar-prefetch arg)

@@ -452,6 +452,7 @@ def _invoke(s, cnt, prev_col, params, vecs, masks, win, log_payload,
     )
     return pl.pallas_call(
         functools.partial(_steady_kernel, BR, cap, L, pconsts, local),
+        name="raft_step",
         out_shape=[
             jax.ShapeDtypeStruct((cap, M), log_payload.dtype),
             jax.ShapeDtypeStruct((TL, cap), log_term.dtype),
@@ -1095,6 +1096,7 @@ def _run_pipeline(state, wins, cnts, s0, prev0, params, vecs, masks,
     outs = pl.pallas_call(
         functools.partial(_steady_pipeline_kernel, BR, cap, L, G, P,
                           ec_consts, local),
+        name="raft_pipeline",
         out_shape=[
             jax.ShapeDtypeStruct((cap, M), state.log_payload.dtype),
             jax.ShapeDtypeStruct((TL, cap), state.log_term.dtype),
@@ -1221,6 +1223,7 @@ def _run_turnover(state, wins, s0, params, vecs, BR, CB, WB, P, T, cap,
     outs = pl.pallas_call(
         functools.partial(_turnover_kernel, BR, cap, L, G, P, ec_consts,
                           local),
+        name="raft_turnover",
         out_shape=[
             jax.ShapeDtypeStruct((cap, M), state.log_payload.dtype),
             jax.ShapeDtypeStruct((TL, cap), state.log_term.dtype),

@@ -25,9 +25,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from raft_tpu.core.ring import pallas_interpret
+from raft_tpu.core import ring
 from raft_tpu.ec import gf
 from raft_tpu.ec.rs import RSCode
+
+
+def pallas_interpret() -> bool:
+    """``core.ring.pallas_interpret()``, looked up at each call: a caller
+    that swaps that function for a while leaves nothing bound here."""
+    return ring.pallas_interpret()
 
 
 def _bit_consts(matrix: np.ndarray) -> np.ndarray:
@@ -98,6 +104,7 @@ def _parity_pallas(k: int, m: int, consts_key, data_sliced: jax.Array,
     bb = _row_block(B)
     return pl.pallas_call(
         partial(_parity_kernel, consts),
+        name="rs_parity",
         out_shape=jax.ShapeDtypeStruct((m, B, Sk), jnp.uint8),
         grid=(B // bb,),
         in_specs=[pl.BlockSpec((k, bb, Sk), lambda i: (0, i, 0))],
@@ -196,6 +203,7 @@ def _encode_fold_pallas(k: int, m: int, consts_key, data: jax.Array,
     bb = _row_block(B)
     parity = pl.pallas_call(
         partial(_parity_cols_kernel, consts, sk),
+        name="rs_encode_fold",
         out_shape=jax.ShapeDtypeStruct((B, m * sk), jnp.uint8),
         grid=(B // bb,),
         in_specs=[pl.BlockSpec((bb, S), lambda i: (i, 0))],

@@ -63,7 +63,8 @@ differential-test join key between the golden model, the engine, and
 - ``profiling`` — on-demand ``jax.profiler`` capture
   (``/profile?seconds=N``) merged with the span Perfetto export into
   one timeline artifact, plus per-launch ``StepTraceAnnotation``
-  boundaries and the bench device-time helpers.
+  boundaries, the pipelined ingest's phase spans (``phase``, read back by
+  ``program_spans``) and the bench device-time helper.
 """
 
 from raft_tpu.obs import blackbox

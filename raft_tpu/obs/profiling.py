@@ -6,19 +6,30 @@ readback as much as the program; a profiler trace records the on-device
 execution span of each compiled module, which is exact regardless of
 dispatch latency.
 
-Bench helpers (the original bench-only role): ``device_seconds`` runs
+Bench helper (the original bench-only role): ``device_seconds`` runs
 one call under a trace and returns the device-side duration of the
 longest compiled module in it (for a bench body that is one ``jit``
-scan, that IS the program). ``op_breakdown`` aggregates per-op device
-durations from the same trace for kernel-level attribution.
+scan, that IS the program).
 
 On-demand capture (the compile-&-memory-plane promotion):
 
 - :func:`launch_annotation` — a ``jax.profiler.StepTraceAnnotation``
   the engines wrap around each launch boundary (the fused window, the
   per-tick replicate, the batched group launch) so a capture segments
-  by launch. It is a nullcontext unless a capture is ACTIVE — the
-  detached cost is one module-bool test per launch, no device traffic.
+  by launch.
+- :func:`phase` — a ``jax.profiler.TraceAnnotation`` around one host
+  phase of the pipelined ingest (``RaftEngine.submit_pipelined``:
+  ``raft.intake``, ``raft.chunk``, ``raft.gate``, ``raft.pack``,
+  ``raft.dispatch``, ``raft.device_wait``, ``raft.account``,
+  ``raft.commit``), with integer stats (entries, bytes handed to the
+  device) riding on the span, on the profiler's clock.
+  :func:`program_spans` reads them back from a capture and
+  :func:`self_ns` gives each span's self time.
+
+  Both return a shared nullcontext unless a profiler session is on
+  (``TraceAnnotation.is_enabled()``, whoever started it: a
+  ``jax.profiler.start_trace`` or :func:`capture_profile`) — the
+  detached cost is one call per site, no allocation, no device traffic.
 - :func:`capture_profile` — capture ``seconds`` of wall time while the
   engine keeps running (the OpsServer ``/profile?seconds=N`` endpoint),
   then merge the device trace with the span tracker's Perfetto export
@@ -42,7 +53,7 @@ import shutil
 import tempfile
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -64,11 +75,10 @@ def resolve_profile_dir(profile_dir: Optional[str]) -> Optional[str]:
 
 
 # ----------------------------------------------------- launch annotations
-_capture_active = False
 _capture_lock = threading.Lock()
 #: shared detached context: nullcontext is stateless and reentrant, so
-#: the per-launch detached cost stays one module-bool test + one return
-#: (no allocation on the hot dispatch path)
+#: the per-site detached cost stays one enabled test + one return (no
+#: allocation on the hot dispatch path)
 _NULL = contextlib.nullcontext()
 
 
@@ -76,16 +86,74 @@ class CaptureBusy(RuntimeError):
     """A profiler capture is already in flight (one session allowed)."""
 
 
-def capture_active() -> bool:
-    return _capture_active
-
-
 def launch_annotation(name: str, step: int):
-    """A ``StepTraceAnnotation`` while a capture is active, else the
+    """A ``StepTraceAnnotation`` while a profiler session is on, else the
     shared detached nullcontext (see module docstring)."""
-    if not _capture_active:
+    if not jax.profiler.TraceAnnotation.is_enabled():
         return _NULL
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+def phase(name: str, **stats: int):
+    """A ``TraceAnnotation`` named ``name`` carrying ``stats`` while a
+    profiler session is on, else the shared detached nullcontext.
+    Stats known only inside the span are added with
+    ``span.set_metadata(...)`` on the entered value, which is None when
+    detached."""
+    ann = jax.profiler.TraceAnnotation
+    if not ann.is_enabled():
+        return _NULL
+    return ann(name, **stats)
+
+
+class HostSpan(NamedTuple):
+    """One host span read back from a capture; ``thread`` numbers the
+    host line (thread) it ran on, which nesting is judged within."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    stats: Dict[str, int]
+    thread: int
+
+
+def program_spans(trace_dir: str, prefix: str = "raft.") -> List[HostSpan]:
+    """The host spans named ``prefix``* in the newest ``.xplane.pb``
+    under ``trace_dir``, sorted by start, with their integer stats."""
+    from jax.profiler import ProfileData
+
+    runs = sorted(glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not runs:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    lines = [ln for plane in ProfileData.from_file(runs[-1]).planes
+             if plane.name.startswith("/host:") for ln in plane.lines]
+    out: List[HostSpan] = []
+    for thread, line in enumerate(lines):
+        for e in line.events:
+            if e.name.startswith(prefix):
+                a = int(e.start_ns)
+                out.append(HostSpan(e.name, a, a + int(e.duration_ns),
+                                    dict(e.stats), thread))
+    return sorted(out, key=lambda s: (s.start_ns, -s.end_ns))
+
+
+def self_ns(spans: List[HostSpan]) -> List[int]:
+    """Each span's duration less the part of it that the spans nested
+    directly in it (same thread, by containment) cover."""
+    order = sorted(range(len(spans)), key=lambda i: (
+        spans[i].thread, spans[i].start_ns, -spans[i].end_ns))
+    child = [0] * len(spans)
+    stack: List[int] = []
+    for i in order:
+        s = spans[i]
+        while stack and (spans[stack[-1]].thread != s.thread
+                         or spans[stack[-1]].end_ns <= s.start_ns):
+            stack.pop()
+        if stack:
+            child[stack[-1]] += s.end_ns - s.start_ns
+        stack.append(i)
+    return [s.end_ns - s.start_ns - c for s, c in zip(spans, child)]
 
 
 # ------------------------------------------------------ on-demand capture
@@ -135,7 +203,6 @@ def capture_profile(
     the timeline); ``keep_python_frames=True`` keeps everything, and
     with a configured destination the raw xplane dump is preserved
     next to the artifact either way."""
-    global _capture_active
     if not _capture_lock.acquire(blocking=False):
         raise CaptureBusy("a profiler capture is already in flight")
     base = resolve_profile_dir(profile_dir)
@@ -147,11 +214,9 @@ def capture_profile(
         raw = tempfile.mkdtemp(prefix="raw_", dir=base)
         cleanup_raw = True
         jax.profiler.start_trace(raw)
-        _capture_active = True
         try:
             sleep(max(seconds, 0.0))
         finally:
-            _capture_active = False
             # always close the session — a leaked session poisons every
             # later start_trace (same contract as device_seconds)
             jax.profiler.stop_trace()
@@ -253,20 +318,3 @@ def device_seconds(
         if trace_dir is None:
             shutil.rmtree(tmp, ignore_errors=True)
 
-
-def op_breakdown(trace_dir: str, top: int = 20):
-    """[(op_name, calls, total_ms)] for the latest trace in ``trace_dir``."""
-    evs = _load_latest_trace(trace_dir)
-    pids = _device_pids(evs)
-    agg = {}
-    for e in evs:
-        if e.get("ph") == "X" and e.get("pid") in pids:
-            nm = str(e.get("name", ""))
-            if nm.startswith("jit_"):
-                continue
-            c, t = agg.get(nm, (0, 0.0))
-            agg[nm] = (c + 1, t + float(e.get("dur", 0)))
-    return [
-        (nm, c, t / 1e3)
-        for nm, (c, t) in sorted(agg.items(), key=lambda kv: -kv[1][1])[:top]
-    ]

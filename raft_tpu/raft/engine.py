@@ -466,6 +466,9 @@ class RaftEngine:
         #   pinned byte-identical either way.
         self.fused_launches = 0
         self.fused_ticks = 0
+        self._pipelined_chunks = 0
+        #   submit_pipelined chunks so far: the id a chunk's phase spans
+        #   share (obs.profiling.phase)
         self._fused_driver = None
         if self.fuse_k > 1:
             from raft_tpu.raft.steady import FusedDriver
@@ -941,244 +944,302 @@ class RaftEngine:
         durability reporting matches ``submit`` (leadership loss mid-chunk
         re-queues refused entries for later ticks; they commit later or
         read as lost). Entries already queued via ``submit`` are folded in
-        ahead of ``payloads`` so the two APIs never reorder."""
+        ahead of ``payloads`` so the two APIs never reorder.
+
+        While a profiler session is on, the call, each chunk and each host
+        phase of a chunk are spans on the profiler's clock
+        (``obs.profiling.phase``; docs/OBSERVABILITY.md)."""
+        with _profiling.phase("raft.submit_pipelined", entries=len(payloads)):
+            return self._submit_pipelined(payloads)
+
+    def _submit_pipelined(self, payloads: List[bytes]) -> List[int]:
         cfg = self.cfg
         r = self.leader_id
         if r is None:
             raise RuntimeError("submit_pipelined requires a current leader")
-        for p in payloads:  # validate all before assigning any seq
-            if len(p) != cfg.entry_bytes:
-                raise ValueError(
-                    f"payload must be exactly {cfg.entry_bytes} bytes"
-                )
-        # the pipelined path owns the queue wholesale from here on
-        # (swaps, re-queues, deferred splices): the staging mirror
-        # cannot track it. Detach the driver around the intake so the
-        # per-submit staging hook doesn't pay a device copy per batch
-        # that the reset below would immediately discard.
-        drv, self._fused_driver = self._fused_driver, None
-        try:
-            seqs = [self.submit(p) for p in payloads]
-        finally:
-            self._fused_driver = drv
-        pending, self._queue = self._queue, []
-        if self._fused_driver is not None:
-            self._fused_driver.on_queue_replaced()
-        # Configuration entries do not ride pipelined scans: a chunk would
-        # keep committing batches beyond the entry under the stale member
-        # mask. Stop the pipeline before the first config entry; the tick
-        # path ingests it with the new mask (see _fire_leader_tick).
-        cut = next((i for i, (q, _) in enumerate(pending)
-                    if q in self._config_seqs), None)
-        deferred: List[Tuple[int, bytes]] = []
-        if cut is not None:
-            deferred = pending[cut:]
-            pending = pending[:cut]
+        phase = _profiling.phase
+        with phase("raft.intake"):
+            for p in payloads:  # validate all before assigning any seq
+                if len(p) != cfg.entry_bytes:
+                    raise ValueError(
+                        f"payload must be exactly {cfg.entry_bytes} bytes"
+                    )
+            # the pipelined path owns the queue wholesale from here on
+            # (swaps, re-queues, deferred splices): the staging mirror
+            # cannot track it. Detach the driver around the intake so the
+            # per-submit staging hook doesn't pay a device copy per batch
+            # that the reset below would immediately discard.
+            drv, self._fused_driver = self._fused_driver, None
+            try:
+                seqs = [self.submit(p) for p in payloads]
+            finally:
+                self._fused_driver = drv
+            pending, self._queue = self._queue, []
+            if self._fused_driver is not None:
+                self._fused_driver.on_queue_replaced()
+            # Configuration entries do not ride pipelined scans: a chunk
+            # would keep committing batches beyond the entry under the
+            # stale member mask. Stop the pipeline before the first config
+            # entry; the tick path ingests it with the new mask (see
+            # _fire_leader_tick).
+            cut = next((i for i, (q, _) in enumerate(pending)
+                        if q in self._config_seqs), None)
+            deferred: List[Tuple[int, bytes]] = []
+            if cut is not None:
+                deferred = pending[cut:]
+                pending = pending[:cut]
         B = cfg.batch_size
         T_ring = cfg.log_capacity // B
         while pending:
-            if self.leader_id != r or not self.alive[r]:
-                break
-            leader_last = int(self._fetch(self.state.last_index)[r])
-            eff = self._reach(r)
-            steps = (
-                self.state.capacity - (leader_last - self.commit_watermark)
-            ) // B
-            if steps <= 0:
-                # ring full of uncommitted entries — the regular tick path
-                # must drain commits first; leave the rest queued
-                break
-            take = min(len(pending), steps * B)
-            # Fixed scan length: pad the chunk with zero-count (heartbeat)
-            # steps so every chunk compiles to the SAME [T, B, L] program —
-            # a varying T would trigger a fresh XLA compile per chunk
-            # length, dwarfing the scan itself.
-            T = T_ring
-            eligible = self._pipeline_eligible(r, take, T, leader_last, eff)
-            # ALL rows in the gate's verified accept set — the kernel's
-            # own turnover predicate evaluated on the same evidence. Only
-            # the write-only turnover branch is certified across ring
-            # laps, so the lap decision and allow_turnover below share
-            # this one value: a quorum-but-not-all accept set must
-            # neither take the lapped shape (the aliased fallback is
-            # uncertified past one turnover) nor compile the turnover
-            # branch it cannot reach.
-            all_accept = bool(eligible and self._gate_accept.all())
-            # Multi-lap fast path: the eligibility legs are T-independent
-            # given take == T*B, and on an all-accept cluster the
-            # write-only turnover kernel is valid across ring laps (each
-            # step commits before its slots are revisited), so a backlog
-            # covering pipeline_max_laps ring turnovers rides ONE launch.
-            # All-or-nothing on the lap count keeps the compile set at
-            # exactly two programs.
-            if (
-                all_accept and cfg.pipeline_max_laps > 1
-                and len(pending) >= cfg.pipeline_max_laps * T_ring * B
-            ):
-                T = cfg.pipeline_max_laps * T_ring
-                take = T * B
-            chunk = pending[:take]
-            used = -(-take // B)
-            counts = np.zeros(T, np.int32)
-            counts[:used] = B
-            if used:
-                counts[used - 1] = take - (used - 1) * B
-            data = self._pack_entries(chunk, T * B)
-            if cfg.ec_enabled:
-                from raft_tpu.ec.kernels import encode_fold_device
+            self._pipelined_chunks += 1
+            chunk_id = self._pipelined_chunks
+            with phase("raft.chunk", chunk=chunk_id) as chunk_span:
+                with phase("raft.gate", chunk=chunk_id):
+                    if self.leader_id != r or not self.alive[r]:
+                        break
+                    leader_last = int(self._fetch(self.state.last_index)[r])
+                    eff = self._reach(r)
+                    steps = (
+                        self.state.capacity
+                        - (leader_last - self.commit_watermark)
+                    ) // B
+                    if steps <= 0:
+                        # ring full of uncommitted entries — the regular
+                        # tick path must drain commits first; leave the
+                        # rest queued
+                        break
+                    take = min(len(pending), steps * B)
+                    # Fixed scan length: pad the chunk with zero-count
+                    # (heartbeat) steps so every chunk compiles to the SAME
+                    # [T, B, L] program — a varying T would trigger a fresh
+                    # XLA compile per chunk length, dwarfing the scan
+                    # itself.
+                    T = T_ring
+                    eligible = self._pipeline_eligible(
+                        r, take, T, leader_last, eff
+                    )
+                    # ALL rows in the gate's verified accept set — the
+                    # kernel's own turnover predicate evaluated on the same
+                    # evidence. Only the write-only turnover branch is
+                    # certified across ring laps, so the lap decision and
+                    # allow_turnover below share this one value: a
+                    # quorum-but-not-all accept set must neither take the
+                    # lapped shape (the aliased fallback is uncertified
+                    # past one turnover) nor compile the turnover branch it
+                    # cannot reach.
+                    all_accept = bool(eligible and self._gate_accept.all())
+                    # Multi-lap fast path: the eligibility legs are
+                    # T-independent given take == T*B, and on an all-accept
+                    # cluster the write-only turnover kernel is valid
+                    # across ring laps (each step commits before its slots
+                    # are revisited), so a backlog covering
+                    # pipeline_max_laps ring turnovers rides ONE launch.
+                    # All-or-nothing on the lap count keeps the compile set
+                    # at exactly two programs.
+                    if (
+                        all_accept and cfg.pipeline_max_laps > 1
+                        and len(pending) >= cfg.pipeline_max_laps * T_ring * B
+                    ):
+                        T = cfg.pipeline_max_laps * T_ring
+                        take = T * B
+                if chunk_span is not None:
+                    chunk_span.set_metadata(entries=take, padded=T * B)
+                with phase("raft.pack", chunk=chunk_id) as pack_span:
+                    chunk = pending[:take]
+                    used = -(-take // B)
+                    counts = np.zeros(T, np.int32)
+                    counts[:used] = B
+                    if used:
+                        counts[used - 1] = take - (used - 1) * B
+                    data = self._pack_entries(chunk, T * B)
+                    if cfg.ec_enabled:
+                        from raft_tpu.ec.kernels import encode_fold_device
 
-                folded = encode_fold_device(self._code, jnp.asarray(data))
-                payload_stack = folded.reshape(T, B, -1)
-            else:
-                payload_stack = fold_batch(data, cfg.rows).reshape(
-                    T, B, -1
-                )
-            pre_lasts = self._pre_lasts()
-            floor, fpt = self._floor_attest(r)
-            dev_pre = self._dev_pre_chunk()
-            if eligible:
-                # The saturated fast path: the whole full-ring chunk as
-                # ONE kernel launch (core.step_pallas.steady_pipeline_tpu
-                # via the transport). The host gate below implies the
-                # kernel's launch-feasibility predicate, so every step
-                # ingests and commits a full batch — bookkeeping is the
-                # contiguous mapping, verified by the commit assert.
-                self.state, info = self.t.replicate_pipeline(
-                    self.state, payload_stack, jnp.asarray(counts), r,
-                    self.leader_term, jnp.asarray(eff),
-                    jnp.asarray(self.slow),
-                    # the pipeline kernel takes the bool VOTER plane
-                    # directly (no packed-mask decomposition on this
-                    # entry point — unlike replicate/scan_replicate)
-                    member=(jnp.asarray(self.member)
-                            if self.cfg.max_replicas is not None else None),
-                    repair_floor=floor, floor_prev_term=fpt,
-                    term_floor=self._term_floor,
-                    # write-only turnover only when the host's verified
-                    # accept set covers EVERY row (same value as the lap
-                    # gate above — see its comment); with False the
-                    # program is the plain pipeline-vs-scan two-way cond
-                    allow_turnover=all_accept,
-                )
-                self._note_truncations(pre_lasts)
-                self._dev_record_chunk(dev_pre, info, r, self.leader_term, T)
-                final_commit = int(info.commit_index)
-                if final_commit != leader_last + take:
-                    # The host gate and the kernel's feasibility predicate
-                    # are meant to be equivalent; a desync means mappings
-                    # for the chunk cannot be trusted — fail loudly
-                    # rather than mis-account durable entries. BUT first
-                    # reconcile, so the exception is survivable: account
-                    # the committed prefix (it is durable — its bytes must
-                    # never be re-queued), then truncate the orphaned
-                    # uncommitted suffix off the device log. Without the
-                    # truncation the re-queued payloads would coexist with
-                    # an unaccounted device copy, and a later repair tick
-                    # could replicate and commit both.
-                    done = min(max(final_commit - leader_last, 0), take)
-                    self._account_chunk_prefix(
-                        r, chunk, done, leader_last, eff
-                    )
-                    self._truncate_uncommitted_tail(
-                        leader_last + done,
-                        self._fetch(self.state.last_index),
-                    )
-                    # chunk[:done] is committed and stays accounted; the
-                    # rest of the chunk re-queues for a later tick
-                    self._queue = (
-                        list(chunk[done:]) + pending[take:] + deferred
-                        + self._queue
-                    )
-                    raise RuntimeError(
-                        f"pipeline chunk shortfall: committed "
-                        f"{final_commit}, expected {leader_last + take} "
-                        "(host feasibility gate out of sync with the "
-                        "kernel's launch predicate); device log "
-                        "reconciled, uncommitted remainder re-queued"
-                    )
-                self._account_chunk_prefix(r, chunk, take, leader_last, eff)
-                pending = pending[take:]
-                self._confirm_reads(
-                    r, self.leader_term, eff, int(info.max_term)
-                )
-                self._update_steady(r, info.match, eff)
-                if int(info.max_term) > self.leader_term:
-                    self._step_down_leader(r, int(info.max_term))
-                    break
-                continue
-            self.state, infos = self.t.replicate_many(
-                self.state, payload_stack, jnp.asarray(counts), r,
-                self.leader_term, jnp.asarray(eff),
-                jnp.asarray(self.slow),
-                repair=self._repair_program(),
-                member=self._member_arg(),
-                repair_floor=floor,
-                floor_prev_term=fpt,
-                term_floor=self._term_floor,
-            )
-            self._note_truncations(pre_lasts)
-            if dev_pre is not None:
-                # the scanned path stacks per-step infos; the chunk
-                # transition is judged against the final step's
-                self._dev_record_chunk(
-                    dev_pre, jax.tree.map(lambda a: a[-1], infos),
-                    r, self.leader_term, T,
-                )
-            # ---- one host sync for the whole chunk ----
-            frontier = np.asarray(infos.frontier_len)
-            max_term = int(np.max(np.asarray(infos.max_term)))
-            final_commit = int(np.asarray(infos.commit_index)[-1])
-            idx = leader_last
-            pos = 0
-            refused: List[Tuple[int, bytes]] = []
-            for t in range(T):
-                cnt, ing = int(counts[t]), int(frontier[t])
-                for i, (seq, p) in enumerate(chunk[pos:pos + cnt]):
-                    if i < ing:
-                        idx += 1
-                        self._seq_at_index[idx] = seq
-                        self._uncommitted[idx] = (p, self.leader_term)
-                        self._note_config_ingest(idx, seq, self.leader_term)
+                        folded = encode_fold_device(
+                            self._code, jnp.asarray(data)
+                        )
+                        payload_stack = folded.reshape(T, B, -1)
                     else:
-                        refused.append((seq, p))
-                pos += cnt
-            pending = refused + pending[take:]
-            # Durability fence FIRST (same ordering as the tick path): the
-            # chunk's term adoptions reach disk before any externally
-            # observable action — _advance_commit archives entries and
-            # advances the durability-visible watermark (ckpt.votelog:
-            # "persist between the step and any such action").
-            self.terms[eff] = np.maximum(self.terms[eff], self.leader_term)
-            self._persist_votes()
-            self._advance_commit(r, final_commit)
-            self._confirm_reads(r, self.leader_term, eff, max_term)
-            self._update_steady(r, infos.match[-1], eff)
-            if max_term > self.leader_term:
-                # deposed mid-chunk: hand the rest back to the queue
-                self._step_down_leader(r, max_term)
-                break
-            if refused:
-                break  # no progress is possible right now; don't spin
+                        payload_stack = fold_batch(data, cfg.rows).reshape(
+                            T, B, -1
+                        )
+                    pre_lasts = self._pre_lasts()
+                    floor, fpt = self._floor_attest(r)
+                    dev_pre = self._dev_pre_chunk()
+                    if pack_span is not None:
+                        # the host arrays this chunk hands to the device:
+                        # the raw lap (encoded on the device) or the
+                        # folded stack, and the per-chunk vectors
+                        lap = data if cfg.ec_enabled else payload_stack
+                        pack_span.set_metadata(bytes=int(
+                            lap.nbytes + counts.nbytes + eff.nbytes
+                            + self.slow.nbytes
+                        ))
+                if eligible:
+                    # The saturated fast path: the whole full-ring chunk as
+                    # ONE kernel launch (core.step_pallas.steady_pipeline_tpu
+                    # via the transport). The host gate below implies the
+                    # kernel's launch-feasibility predicate, so every step
+                    # ingests and commits a full batch — bookkeeping is the
+                    # contiguous mapping, verified by the commit assert.
+                    with phase("raft.dispatch", chunk=chunk_id):
+                        self.state, info = self.t.replicate_pipeline(
+                            self.state, payload_stack, jnp.asarray(counts),
+                            r, self.leader_term, jnp.asarray(eff),
+                            jnp.asarray(self.slow),
+                            # the pipeline kernel takes the bool VOTER
+                            # plane directly (no packed-mask decomposition
+                            # on this entry point — unlike
+                            # replicate/scan_replicate)
+                            member=(jnp.asarray(self.member)
+                                    if self.cfg.max_replicas is not None
+                                    else None),
+                            repair_floor=floor, floor_prev_term=fpt,
+                            term_floor=self._term_floor,
+                            # write-only turnover only when the host's
+                            # verified accept set covers EVERY row (same
+                            # value as the lap gate above — see its
+                            # comment); with False the program is the plain
+                            # pipeline-vs-scan two-way cond
+                            allow_turnover=all_accept,
+                        )
+                    with phase("raft.device_wait", chunk=chunk_id):
+                        self._note_truncations(pre_lasts)
+                        self._dev_record_chunk(
+                            dev_pre, info, r, self.leader_term, T
+                        )
+                        final_commit = int(info.commit_index)
+                    if final_commit != leader_last + take:
+                        # The host gate and the kernel's feasibility
+                        # predicate are meant to be equivalent; a desync
+                        # means mappings for the chunk cannot be trusted —
+                        # fail loudly rather than mis-account durable
+                        # entries. BUT first reconcile, so the exception is
+                        # survivable: account the committed prefix (it is
+                        # durable — its bytes must never be re-queued),
+                        # then truncate the orphaned uncommitted suffix off
+                        # the device log. Without the truncation the
+                        # re-queued payloads would coexist with an
+                        # unaccounted device copy, and a later repair tick
+                        # could replicate and commit both.
+                        done = min(max(final_commit - leader_last, 0), take)
+                        self._account_chunk_prefix(
+                            r, chunk, done, leader_last, eff, chunk_id
+                        )
+                        self._truncate_uncommitted_tail(
+                            leader_last + done,
+                            self._fetch(self.state.last_index),
+                        )
+                        # chunk[:done] is committed and stays accounted;
+                        # the rest of the chunk re-queues for a later tick
+                        self._queue = (
+                            list(chunk[done:]) + pending[take:] + deferred
+                            + self._queue
+                        )
+                        raise RuntimeError(
+                            f"pipeline chunk shortfall: committed "
+                            f"{final_commit}, expected {leader_last + take} "
+                            "(host feasibility gate out of sync with the "
+                            "kernel's launch predicate); device log "
+                            "reconciled, uncommitted remainder re-queued"
+                        )
+                    self._account_chunk_prefix(
+                        r, chunk, take, leader_last, eff, chunk_id
+                    )
+                    pending = pending[take:]
+                    with phase("raft.commit", chunk=chunk_id):
+                        self._confirm_reads(
+                            r, self.leader_term, eff, int(info.max_term)
+                        )
+                        self._update_steady(r, info.match, eff)
+                        if int(info.max_term) > self.leader_term:
+                            self._step_down_leader(r, int(info.max_term))
+                            break
+                    continue
+                with phase("raft.dispatch", chunk=chunk_id):
+                    self.state, infos = self.t.replicate_many(
+                        self.state, payload_stack, jnp.asarray(counts), r,
+                        self.leader_term, jnp.asarray(eff),
+                        jnp.asarray(self.slow),
+                        repair=self._repair_program(),
+                        member=self._member_arg(),
+                        repair_floor=floor,
+                        floor_prev_term=fpt,
+                        term_floor=self._term_floor,
+                    )
+                # ---- one host sync for the whole chunk ----
+                with phase("raft.device_wait", chunk=chunk_id):
+                    self._note_truncations(pre_lasts)
+                    if dev_pre is not None:
+                        # the scanned path stacks per-step infos; the chunk
+                        # transition is judged against the final step's
+                        self._dev_record_chunk(
+                            dev_pre, jax.tree.map(lambda a: a[-1], infos),
+                            r, self.leader_term, T,
+                        )
+                    frontier = np.asarray(infos.frontier_len)
+                    max_term = int(np.max(np.asarray(infos.max_term)))
+                    final_commit = int(np.asarray(infos.commit_index)[-1])
+                with phase("raft.account", chunk=chunk_id):
+                    idx = leader_last
+                    pos = 0
+                    refused: List[Tuple[int, bytes]] = []
+                    for t in range(T):
+                        cnt, ing = int(counts[t]), int(frontier[t])
+                        for i, (seq, p) in enumerate(chunk[pos:pos + cnt]):
+                            if i < ing:
+                                idx += 1
+                                self._seq_at_index[idx] = seq
+                                self._uncommitted[idx] = (p, self.leader_term)
+                                self._note_config_ingest(
+                                    idx, seq, self.leader_term
+                                )
+                            else:
+                                refused.append((seq, p))
+                        pos += cnt
+                    pending = refused + pending[take:]
+                    # Durability fence FIRST (same ordering as the tick
+                    # path): the chunk's term adoptions reach disk before
+                    # any externally observable action — _advance_commit
+                    # archives entries and advances the durability-visible
+                    # watermark (ckpt.votelog: "persist between the step
+                    # and any such action").
+                    self.terms[eff] = np.maximum(
+                        self.terms[eff], self.leader_term
+                    )
+                    self._persist_votes()
+                with phase("raft.commit", chunk=chunk_id):
+                    self._advance_commit(r, final_commit)
+                    self._confirm_reads(r, self.leader_term, eff, max_term)
+                    self._update_steady(r, infos.match[-1], eff)
+                    if max_term > self.leader_term:
+                        # deposed mid-chunk: hand the rest back to the queue
+                        self._step_down_leader(r, max_term)
+                        break
+                if refused:
+                    break  # no progress is possible right now; don't spin
         self._queue = pending + deferred + self._queue
         if self.leader_id == r:
             self._reset_heard_timers(r)
         return seqs
 
     def _account_chunk_prefix(self, r: int, chunk, n: int,
-                              leader_last: int, eff) -> None:
+                              leader_last: int, eff, chunk_id: int) -> None:
         """Durable accounting for the first ``n`` entries of a pipeline
         chunk at contiguous indices after ``leader_last``: stamp seq and
         payload bookkeeping, fence term durability to disk, then advance
         the commit watermark (archive + ack). Shared by the fast path's
         success and shortfall-reconcile branches so the two can never
         drift on what "durably accounted" means."""
-        for i, (seq, p) in enumerate(chunk[:n]):
-            idx = leader_last + 1 + i
-            self._seq_at_index[idx] = seq
-            self._uncommitted[idx] = (p, self.leader_term)
-        self.terms[eff] = np.maximum(self.terms[eff], self.leader_term)
-        self._persist_votes()
-        self._advance_commit(r, leader_last + n)
+        with _profiling.phase("raft.account", chunk=chunk_id):
+            for i, (seq, p) in enumerate(chunk[:n]):
+                idx = leader_last + 1 + i
+                self._seq_at_index[idx] = seq
+                self._uncommitted[idx] = (p, self.leader_term)
+            self.terms[eff] = np.maximum(self.terms[eff], self.leader_term)
+            self._persist_votes()
+        with _profiling.phase("raft.commit", chunk=chunk_id):
+            self._advance_commit(r, leader_last + n)
 
     def _pipeline_eligible(self, r: int, take: int, T: int,
                            leader_last: int, eff) -> bool:

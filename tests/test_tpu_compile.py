@@ -5,7 +5,8 @@ What interpret mode cannot show, the chip's compiler refuses here: block
 shapes off the tiling, more VMEM than a kernel may use, programs that do
 not fit the device. Each test compiles one kernel or program at the size
 ``chip_smoke.py`` drives it and asserts the Mosaic kernel is in the
-compiled module (``tpu_custom_call``).
+compiled module (``tpu_custom_call``), under the stable name its
+``pallas_call`` gives it (what a profiler trace shows for the op).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and xdist workers all
@@ -15,6 +16,7 @@ import this file (on-chip-measurement guide §2).
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,9 +94,11 @@ def _scalars(sharding, n):
     return [_sds((), jnp.int32, sharding) for _ in range(n)]
 
 
-def _assert_kernel(lowered):
+def _assert_kernel(lowered, *names):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    for name in names:
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
 
 
 def _step_args(cfg, sh):
@@ -116,7 +120,7 @@ class TestSingleChipKernels:
         _assert_kernel(steady_replicate_step_tpu.lower(
             _state(cfg, one_chip), win, *_step_args(cfg, one_chip),
             commit_quorum=cfg.commit_quorum, interpret=False,
-        ))
+        ), "raft_step")
 
     @pytest.mark.parametrize("cap,T,turnover", [
         (1 << 20, 32, False),        # steady pipeline, T=32
@@ -137,7 +141,7 @@ class TestSingleChipKernels:
         _assert_kernel(fn.lower(
             _state(cfg, one_chip), wins, counts, leader, term, vec, vec,
             fpt, rf, None, tf,
-        ))
+        ), "raft_turnover" if turnover else "raft_pipeline")
 
     def test_ring_write_2e20(self, mosaic, one_chip):
         from raft_tpu.core.ring_pallas import write_window_cols_tpu
@@ -149,7 +153,25 @@ class TestSingleChipKernels:
             _sds((cfg.log_capacity, M), jnp.int32, one_chip),
             _sds((cfg.batch_size, M), jnp.int32, one_chip), s, count,
             _sds((M,), jnp.bool_, one_chip), interpret=False,
-        ))
+        ), "ring_write")
+
+    def test_ring_write_both_2e20(self, mosaic, one_chip):
+        """The fused payload + term ring write with the conflict check
+        (``core.step``'s ingest at north-star width)."""
+        from raft_tpu.core.ring_pallas import write_window_both_tpu
+
+        cfg = RaftConfig(log_capacity=1 << 20, **NS)
+        C, B, L = cfg.log_capacity, cfg.batch_size, cfg.rows
+        M = L * cfg.shard_words
+        s, count, ws = _scalars(one_chip, 3)
+        _assert_kernel(write_window_both_tpu.lower(
+            _sds((C, M), jnp.int32, one_chip),
+            _sds((L, C), jnp.int32, one_chip),
+            _sds((B, M), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip), s, count, ws,
+            _sds((L,), jnp.bool_, one_chip),
+            _sds((L,), jnp.int32, one_chip), interpret=False,
+        ), "ring_write_both")
 
     def test_ec_fused_steady_step(self, mosaic, one_chip):
         from raft_tpu.core.step_pallas import steady_scan_replicate_tpu
@@ -169,7 +191,7 @@ class TestSingleChipKernels:
         _assert_kernel(fn.lower(
             _state(cfg, one_chip), wins, counts, leader, term, vec, vec,
             fpt, rf, None, tf,
-        ))
+        ), "raft_step")
 
     @pytest.mark.parametrize("rows", [1024, 1 << 17])
     def test_ec_encode_fold(self, no_cache, one_chip, rows):
@@ -180,7 +202,7 @@ class TestSingleChipKernels:
         data = _sds((rows, 264), jnp.uint8, one_chip)
         _assert_kernel(_encode_fold_pallas.lower(
             3, 2, _parity_consts_key(5, 3), data, False,
-        ))
+        ), "rs_encode_fold")
 
     def test_ec_encode(self, no_cache, one_chip):
         from raft_tpu.ec.kernels import encode_pallas
@@ -189,7 +211,7 @@ class TestSingleChipKernels:
         data = _sds((1024, 264), jnp.uint8, one_chip)
         fn = jax.jit(lambda d: encode_pallas(RSCode(5, 3), d,
                                              interpret=False))
-        _assert_kernel(fn.lower(data))
+        _assert_kernel(fn.lower(data), "rs_parity")
 
     @pytest.mark.parametrize("rows", [1024, 1 << 17])
     def test_ec_decode(self, no_cache, one_chip, rows):
@@ -201,7 +223,7 @@ class TestSingleChipKernels:
         shards = _sds((3, rows, 88), jnp.uint8, one_chip)
         fn = jax.jit(lambda s: decode_pallas(RSCode(5, 3), s, [1, 2, 3],
                                              interpret=False))
-        _assert_kernel(fn.lower(shards))
+        _assert_kernel(fn.lower(shards), "rs_parity")
 
 
 class TestMeshPrograms:
