@@ -1009,15 +1009,31 @@ class RaftEngine:
                         # rest queued
                         break
                     take = min(len(pending), steps * B)
-                    # Fixed scan length: pad the chunk with zero-count
-                    # (heartbeat) steps so every chunk compiles to the SAME
-                    # [T, B, L] program — a varying T would trigger a fresh
-                    # XLA compile per chunk length, dwarfing the scan
-                    # itself.
+                    # The lap decision is taken at T_ring: the pipeline
+                    # kernel's geometry is certified for whole laps only.
                     T = T_ring
                     eligible = self._pipeline_eligible(
                         r, take, T, leader_last, eff
                     )
+                    heals = self._repair_program() and not cfg.ec_enabled
+                    if not eligible and take <= B and not heals:
+                        # A chunk that fits in one batch runs one scan
+                        # step, not a lap padded with zero-count
+                        # (heartbeat) steps, when those steps would carry
+                        # no entry and heal no follower: the cluster is
+                        # verified steady (the repair program is off) or
+                        # the step has no repair window (RS). While a
+                        # follower lags on the plain log, every step of
+                        # the lap heals up to B of its entries, so the
+                        # lap stays. Longer partial laps keep the lap for
+                        # now: tests/benchmark_harness/test_phase_split.py
+                        # pins a whole lap's upload for a multi-batch
+                        # round. Once that test follows the sized scan,
+                        # T = the next power of two at or above
+                        # ceil(take / B), as raft/steady.py sizes its
+                        # windows, removes the cliff at one batch. Each
+                        # scan length is its own XLA program.
+                        T = 1
                     # ALL rows in the gate's verified accept set — the
                     # kernel's own turnover predicate evaluated on the same
                     # evidence. Only the write-only turnover branch is
@@ -1068,11 +1084,11 @@ class RaftEngine:
                     dev_pre = self._dev_pre_chunk()
                     if pack_span is not None:
                         # the host arrays this chunk hands to the device:
-                        # the raw lap (encoded on the device) or the
-                        # folded stack, and the per-chunk vectors
-                        lap = data if cfg.ec_enabled else payload_stack
+                        # the raw stack (encoded on the device) or the
+                        # folded one, and the per-chunk vectors
+                        stack = data if cfg.ec_enabled else payload_stack
                         pack_span.set_metadata(bytes=int(
-                            lap.nbytes + counts.nbytes + eff.nbytes
+                            stack.nbytes + counts.nbytes + eff.nbytes
                             + self.slow.nbytes
                         ))
                 if eligible:

@@ -65,7 +65,7 @@ def test_spans_nest_and_count_upload_bytes(tmp_path, kind):
     e = mk_engine(**kind)
     cfg = e.cfg
     e.submit_pipelined(payloads(cfg, 8, 1))       # compile outside the trace
-    n = 100                                       # > one ring: two chunks
+    n = 66              # > one ring: a whole-lap chunk, then 2 entries
     spans = traced(tmp_path, lambda: e.submit_pipelined(payloads(cfg, n, 2)))
     assert e.commit_watermark == 8 + n
 
@@ -77,24 +77,27 @@ def test_spans_nest_and_count_upload_bytes(tmp_path, kind):
     assert len(chunks) == 2
     assert intake.end_ns <= chunks[0].start_ns
     assert sum(c.stats["entries"] for c in chunks) == n
+    assert [c.stats["entries"] for c in chunks] == [64, 2]
     ids = [c.stats["chunk"] for c in chunks]
     assert ids[1] == ids[0] + 1
 
-    T = cfg.log_capacity // cfg.batch_size
-    if cfg.ec_enabled:      # the raw lap goes up; the device encodes it
-        lap = T * cfg.batch_size * cfg.entry_bytes
-    else:                   # the lap folded into every row's lanes
-        lap = T * cfg.batch_size * cfg.rows * cfg.shard_words * 4
-    vectors = T * 4 + 2 * cfg.rows              # counts, alive, slow
+    B = cfg.batch_size
     for c in chunks:
-        assert c.stats["padded"] == T * cfg.batch_size
+        # a chunk of one batch runs one step; a longer one the whole lap
+        T = 1 if c.stats["entries"] <= B else cfg.log_capacity // B
+        if cfg.ec_enabled:  # the raw stack goes up; the device encodes it
+            stack = T * B * cfg.entry_bytes
+        else:               # the stack folded into every row's lanes
+            stack = T * B * cfg.rows * cfg.shard_words * 4
+        vectors = T * 4 + 2 * cfg.rows          # counts, alive, slow
+        assert c.stats["padded"] == T * B
         phases = inside(c, spans)
         assert [s.name for s in phases] == PHASES
         assert all(s.stats["chunk"] == c.stats["chunk"] for s in phases)
         assert [s.end_ns <= t.start_ns
                 for s, t in zip(phases, phases[1:])] == [True] * 5
         (pack,) = [s for s in phases if s.name == "raft.pack"]
-        assert pack.stats["bytes"] == lap + vectors
+        assert pack.stats["bytes"] == stack + vectors
     # self time: each chunk less its phases is the few statements
     # between them; the phases themselves hold no nested spans
     for s, own in zip(spans, self_ns(spans)):

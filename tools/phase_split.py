@@ -12,8 +12,10 @@ result line with one more key, ``phases``:
   for the spans that start inside the window;
 - ``pack_us_per_entry``, ``launch_wait_us_per_entry`` (dispatch and
   device wait less the leader chip's busy time inside them),
-  ``commit_us_per_entry`` (account and commit) and
-  ``h2d_bytes_per_entry`` (the ``bytes`` stat of ``raft.pack``);
+  ``commit_us_per_entry`` (account and commit),
+  ``h2d_bytes_per_entry`` (the ``bytes`` stat of ``raft.pack``) and
+  ``fill_share`` (the entries of the window's ``raft.chunk`` spans over
+  the rows their scans were sized to, their ``padded`` stat);
 - ``host_sum_us_per_entry``: those three times plus intake and gate,
   beside the harness's own ``host_us_per_entry``;
 - ``coverage``: the share of each ``raft.chunk`` its phases cover, of
@@ -81,6 +83,10 @@ def split(run, spans) -> dict:
         "h2d_bytes_per_entry": sum(s.stats.get("bytes", 0) for s, _ in keep
                                    if s.name == "raft.pack") / n,
     }
+    chunks = [s.stats for s, _ in keep if s.name == "raft.chunk"]
+    padded = sum(c.get("padded", 0) for c in chunks)
+    readings["fill_share"] = (sum(c.get("entries", 0) for c in chunks)
+                              / padded if padded else None)
     host_sum = (readings["pack_us_per_entry"]
                 + readings["launch_wait_us_per_entry"]
                 + readings["commit_us_per_entry"]
@@ -117,7 +123,7 @@ def split(run, spans) -> dict:
                 tr.total(tr.intersect(calls, bench_calls))
                 / tr.total(bench_calls) if bench_calls else None),
         },
-        "chunks": sum(1 for s, _ in keep if s.name == "raft.chunk"),
+        "chunks": len(chunks),
         "acked": n,
         "idle_gaps": tr.label_gaps(tr.gaps(busy, lo, hi), named),
     }
