@@ -1987,11 +1987,10 @@ class MultiEngine:
         ``submit_time`` records dropped."""
         from raft_tpu.raft.ledger import evict_commit_stamps
 
-        self.commit_time[g], self.submit_time[g], n = evict_commit_stamps(
+        self.commit_stamps_evicted[g] += evict_commit_stamps(
             self.commit_time[g], self.submit_time[g],
             self._commit_stamp_cap, self._durable_ranges[g],
         )
-        self.commit_stamps_evicted[g] += n
 
     def _evict_group_history(self, g: int) -> None:
         """Archive retention sweep: keep the last ``2 * log_capacity``

@@ -884,16 +884,23 @@ class RaftEngine:
         the stamp SEQUENCE, not of check cadence — the fused K-tick
         path (one check per launch) and the tick path (one per advance)
         end every run with identical dicts, which the fused byte-
-        identity pins compare. The algorithm (bulk C-level rebuilds,
-        numpy run-collapse) lives in ``raft.ledger``, shared verbatim
-        with ``MultiEngine``'s per-group ledgers."""
+        identity pins compare. The algorithm (in-place deletion of the
+        oldest keys, numpy run-collapse) lives in ``raft.ledger``,
+        shared verbatim with ``MultiEngine``'s per-group ledgers.
+
+        An eviction is a ``raft.evict`` span (``obs.profiling.phase``)
+        whose ``evicted`` stat counts the stamps dropped; under the cap
+        there is neither span nor work."""
         from raft_tpu.raft.ledger import evict_commit_stamps
 
-        self.commit_time, self.submit_time, n = evict_commit_stamps(
-            self.commit_time, self.submit_time, self._commit_stamp_cap,
-            self._durable_ranges,
-        )
-        self.commit_stamps_evicted += n
+        over = len(self.commit_time) - self._commit_stamp_cap
+        if over <= 0:
+            return
+        with _profiling.phase("raft.evict", evicted=over):
+            self.commit_stamps_evicted += evict_commit_stamps(
+                self.commit_time, self.submit_time, self._commit_stamp_cap,
+                self._durable_ranges,
+            )
 
     def _pack_entries(self, entries, padded_len: int) -> np.ndarray:
         """(seq, payload) pairs -> u8[padded_len, entry_bytes], zero-padded

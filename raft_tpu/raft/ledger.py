@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import bisect
 from itertools import islice
-from typing import Dict, List, Tuple
+from typing import Dict, List
 
 import numpy as np
 
@@ -62,35 +62,28 @@ def evict_commit_stamps(
     submit_time: Dict[int, float],
     cap: int,
     ranges: List[List[int]],
-) -> Tuple[Dict[int, float], Dict[int, float], int]:
-    """Trim the stamp dicts to exactly ``cap`` retained entries
-    (oldest-first; bulk C-level rebuilds), folding the evicted seqs
-    into ``ranges`` (mutated in place). Returns the new
-    ``(commit_time, submit_time, n_evicted)`` — no-op triple when under
-    the cap."""
+) -> int:
+    """Trim ``commit_time`` to exactly ``cap`` retained entries in place
+    by deleting its ``n_evict`` oldest keys (O(n_evict), whatever the
+    cap), popping the matching ``submit_time`` records and folding the
+    evicted seqs into ``ranges``; all three are mutated. Returns the
+    number evicted, 0 when under the cap."""
     n_evict = len(commit_time) - cap
     if n_evict <= 0:
-        return commit_time, submit_time, 0
-    it = iter(commit_time.items())
-    evicted = list(islice(it, n_evict))
-    commit_time = dict(it)                 # retained tail, C-level
-    if n_evict * 4 < len(submit_time):
-        for seq, _ in evicted:
-            submit_time.pop(seq, None)
-    else:
-        drop = {s for s, _ in evicted}
-        submit_time = {
-            k: v for k, v in submit_time.items() if k not in drop
-        }
+        return 0
+    evicted = list(islice(commit_time, n_evict))
+    for seq in evicted:
+        del commit_time[seq]
+        submit_time.pop(seq, None)
     # fold the evicted seqs into the merged durable intervals:
     # contiguous runs collapse via one numpy pass (seqs stamp in
     # near-ascending order, so the interval list stays tiny — one
     # interval per loss gap)
-    arr = np.fromiter((s for s, _ in evicted), np.int64, n_evict)
+    arr = np.array(evicted, np.int64)
     arr.sort()
     breaks = np.flatnonzero(np.diff(arr) != 1)
     starts = np.concatenate(([0], breaks + 1))
     ends = np.concatenate((breaks, [n_evict - 1]))
     for a, b in zip(arr[starts], arr[ends]):
         merge_durable_range(ranges, int(a), int(b))
-    return commit_time, submit_time, n_evict
+    return n_evict

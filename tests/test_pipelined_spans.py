@@ -15,6 +15,8 @@ Contracts under test:
    or not it makes the same device fetches, compiles nothing more and
    commits the same bytes.
 3. ``launch_annotation`` shows under a plain ``start_trace``.
+4. A commit that takes the stamp dict past its cap is a ``raft.evict``
+   inside ``raft.commit``, its ``evicted`` stat the stamps dropped.
 """
 
 import jax
@@ -119,6 +121,23 @@ def test_spans_nest_and_count_upload_bytes(tmp_path, kind):
                 f.end_ns - f.start_ns for f in reads)
         elif s.name == "raft.chunk":
             assert 0 <= own < (s.end_ns - s.start_ns) / 2
+
+
+def test_evict_span_inside_commit_past_the_cap(tmp_path):
+    e = mk_engine(**PLAIN)
+    cfg = e.cfg
+    cap = e._commit_stamp_cap                        # 2 * 64 stamps
+    e.submit_pipelined(payloads(cfg, 8, 10))         # compile outside
+    spans = traced(tmp_path, lambda: e.submit_pipelined(
+        payloads(cfg, 2 * cap, 11)))
+    assert len(e.commit_time) == cap
+    evicts = [s for s in spans if s.name == "raft.evict"]
+    assert evicts
+    assert all(s.stats["evicted"] > 0 for s in evicts)
+    assert sum(s.stats["evicted"] for s in evicts) \
+        == e.commit_stamps_evicted == 8 + 2 * cap - cap
+    commits = [s for s in spans if s.name == "raft.commit"]
+    assert all(any(s in inside(c, spans) for c in commits) for s in evicts)
 
 
 def test_no_session_builds_no_annotation(monkeypatch):

@@ -21,9 +21,12 @@ result line with one more key, ``phases``:
 - ``h2d_bytes_per_chip_per_entry`` (the ``bytes_per_chip`` stat of
   ``raft.pack``: the most any one chip receives), ``fetch_us_per_entry``
   and ``fetches_per_chunk`` (the ``raft.fetch`` spans: each device read
-  the engine makes, its time counted in the phase around it as well) and
-  ``collective_us_per_entry`` (the benchmark's reader of that name: the
-  leader chip's collective ops);
+  the engine makes, its time counted in the phase around it as well),
+  ``evict_us_per_entry`` and ``evicted_per_chunk`` (the ``raft.evict``
+  spans and their ``evicted`` stat: the commit-stamp eviction, its time
+  counted in ``raft.commit`` as well) and ``collective_us_per_entry``
+  (the benchmark's reader of that name: the leader chip's collective
+  ops);
 - ``coverage``: the share of each ``raft.chunk`` its phases cover, of
   each ``raft.submit_pipelined`` its intake and chunks cover, and of the
   benchmark's ``bench.submit_pipelined`` spans the program's call covers
@@ -51,6 +54,9 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parent.parent
 
 FETCH = "raft.fetch"
+EVICT = "raft.evict"
+#: spans timed inside the phase around them, as before they had a span
+INNER = (FETCH, EVICT)
 CHUNK_PHASES = ("raft.gate", "raft.pack", "raft.dispatch",
                 "raft.device_wait", "raft.account", "raft.commit")
 
@@ -65,9 +71,10 @@ def split(run, spans) -> dict:
 
     t = run.trace
     lo, hi = t.window()
-    reads = [s for s in spans if s.name == FETCH and lo <= s.start_ns < hi]
-    # a device read is timed inside its phase, as before it had a span
-    spans = [s for s in spans if s.name != FETCH]
+    inner = [s for s in spans if s.name in INNER and lo <= s.start_ns < hi]
+    reads = [s for s in inner if s.name == FETCH]
+    evicts = [s for s in inner if s.name == EVICT]
+    spans = [s for s in spans if s.name not in INNER]
     own = self_ns(spans)
     keep = [(s, o) for s, o in zip(spans, own) if lo <= s.start_ns < hi]
     busy = tr.busy(t, run.leader_device, lo, hi)
@@ -101,6 +108,10 @@ def split(run, spans) -> dict:
     readings["fetch_us_per_entry"] = per_entry(
         sum(s.end_ns - s.start_ns for s in reads))
     readings["fetches_per_chunk"] = len(reads) / len(chunks)
+    readings["evict_us_per_entry"] = per_entry(
+        sum(s.end_ns - s.start_ns for s in evicts))
+    readings["evicted_per_chunk"] = sum(
+        s.stats.get("evicted", 0) for s in evicts) / len(chunks)
     readings["collective_us_per_entry"] = load_reader(
         run.root, "collective_us_per_entry")(run)
     padded = sum(c.get("padded", 0) for c in chunks)
@@ -128,7 +139,7 @@ def split(run, spans) -> dict:
     bench_calls = tr.clip(t.span_intervals("bench.submit_pipelined"), lo, hi)
     calls = tr.merge(ivs_by["raft.submit_pipelined"])
     named = list(t.spans) + [(s.name, s.start_ns, s.end_ns)
-                             for s in [s for s, _ in keep] + reads]
+                             for s in [s for s, _ in keep] + inner]
     return {
         **readings,
         "host_sum_us_per_entry": host_sum,
