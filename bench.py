@@ -54,7 +54,7 @@ import numpy as np
 from raft_tpu.admission import Overloaded
 from raft_tpu.config import RaftConfig
 from raft_tpu.core.comm import SingleDeviceComm
-from raft_tpu.core.gates import pipeline_lap_gate, ring_kernel_gate
+from raft_tpu.core.gates import ring_kernel_gate
 from raft_tpu.core.state import init_state
 from raft_tpu.core.step import replicate_step
 from raft_tpu.obs.compile import use_persistent_cache
@@ -173,7 +173,7 @@ def make_scan(cfg: RaftConfig, slow_mask, ec: bool,
         # carry (core.step_pallas) — the same program the engine
         # dispatches on a steady cluster, with its tracked term_floor
         # (single-term pipeline: every index is current-term, floor=1).
-        from raft_tpu.core.step_pallas import steady_pipeline_tpu
+        from raft_tpu.core.step_pallas import steady_scan_replicate_tpu
 
         T = jax.tree.leaves(xs)[0].shape[0]
         counts = jnp.full((T,), cfg.batch_size, jnp.int32)
@@ -195,24 +195,22 @@ def make_scan(cfg: RaftConfig, slow_mask, ec: bool,
             # saturation mode; there is no per-step payload work to hoist)
             wins = mk_payload(jax.tree.map(lambda a: a[0], xs))[None]
 
-        # The saturated pipeline as ONE kernel launch for all T steps
-        # (core.step_pallas.steady_pipeline_tpu); its launch-feasibility
-        # cond falls back to the per-step fused scan when the full-batch
-        # geometry cannot hold. This is the same program the engine's
-        # chunked submit_pipelined pipeline expresses.
+        # The T steps as one fused scan (core.step_pallas.
+        # steady_scan_replicate_tpu), the program the engine's
+        # submit_pipelined chunks run on one chip; step t reads window
+        # t % len(wins).
         from raft_tpu.core.ring import pallas_interpret
 
-        # turnover branch only when the static mask admits all-accept
-        # (an induced-slow row can never accept: compiling the branch
-        # would tax the aliased path through cond unification)
-        allow_turnover = not bool(np.asarray(slow_mask).any())
-
         def scan_fused(state, wins, counts):
-            st, info = steady_pipeline_tpu(
-                state, wins, counts, leader, lterm, alive, slow,
+            P = wins.shape[0]
+            st, info = steady_scan_replicate_tpu(
+                state, jnp.arange(T), counts, leader, lterm, alive, slow,
                 jnp.int32(0), jnp.int32(0), None, jnp.int32(1),
                 commit_quorum=cfg.commit_quorum, ec_consts=ec_consts,
-                interpret=pallas_interpret(), allow_turnover=allow_turnover,
+                interpret=pallas_interpret(), stack_infos=False,
+                mk_payload=lambda t: jax.lax.dynamic_index_in_dim(
+                    wins, t % P, 0, keepdims=False
+                ),
             )
             return st, info.commit_index
 
@@ -784,60 +782,11 @@ def bench_client_latency() -> dict:
         samples.append(time.perf_counter() - t0)
     wall = min(samples)
 
-    # lapped variant: pipeline_max_laps rings per launch amortize the
-    # chunk launch + per-chunk host syncs over a k-fold bigger backlog
-    LAPS = 8
-    cfg_l = RaftConfig(pipeline_max_laps=LAPS)
-    tl = SingleDeviceTransport(cfg_l)
-    launches = []
-    _orig_pipe = tl.replicate_pipeline
-
-    def counting(state, payloads, counts, *a, **k):
-        launches.append(int(counts.shape[0]))
-        return _orig_pipe(state, payloads, counts, *a, **k)
-
-    tl.replicate_pipeline = counting
-    el = RaftEngine(cfg_l, tl)
-    el.run_until_leader()
-    big = LAPS * cfg_l.log_capacity
-    T_lap = LAPS * (cfg_l.log_capacity // cfg_l.batch_size)
-    mk_big = lambda: [rng.integers(0, 256, cfg_l.entry_bytes,
-                                   np.uint8).tobytes() for _ in range(big)]
-    seqs = el.submit_pipelined(mk_big())     # warm
-    assert el.is_durable(seqs[-1])
-    lap_samples = []
-    lap_error = None
-    for _ in range(2):
-        ps = mk_big()
-        launches.clear()
-        t0 = time.perf_counter()
-        seqs = el.submit_pipelined(ps)
-        assert el.is_durable(seqs[-1])
-        if launches != [T_lap]:
-            # the row's amortization claim is only honest if the backlog
-            # really rode ONE lapped launch — a gate fallback to
-            # single-ring chunks must surface as an explicit error field,
-            # never publish as lapped (and never kill the whole suite)
-            lap_error = f"lapped launch not taken: launches={launches}"
-            break
-        lap_samples.append(time.perf_counter() - t0)
-    if lap_error is None:
-        lwall = min(lap_samples)
-        lapped = {
-            "laps": LAPS,
-            "chunk_entries": big,
-            "chunk_wall_ms": round(lwall * 1e3, 1),
-            "wall_us_per_entry": round(lwall * 1e6 / big, 3),
-            "entries_per_sec_wall": round(big / lwall, 1),
-        }
-    else:
-        lapped = {"laps": LAPS, "error": lap_error}
     return {
         "chunk_entries": n,
         "chunk_wall_ms": round(wall * 1e3, 1),
         "wall_us_per_entry": round(wall * 1e6 / n, 3),
         "entries_per_sec_wall": round(n / wall, 1),
-        "lapped_chunk": lapped,
         "metrics": e.metrics.to_json(),
         "note": ("submit->durable-ack wall incl. dispatch and host "
                  "durability bookkeeping; the device-time rows measure "
@@ -2328,7 +2277,9 @@ def bench_mesh1(rng) -> dict:
         np.iinfo(np.int32).min, np.iinfo(np.int32).max,
         (cfg.batch_size, cfg.shard_words), dtype=np.int32,
     )
-    wins = jnp.asarray(words)[None]          # n=1: lanes == shard_words
+    wins = jnp.broadcast_to(                  # n=1: lanes == shard_words
+        jnp.asarray(words), (T_STEPS,) + words.shape
+    )
     counts = jnp.full((T_STEPS,), cfg.batch_size, jnp.int32)
     alive = jnp.ones(1, bool)
     slow = jnp.zeros(1, bool)
@@ -2338,11 +2289,11 @@ def bench_mesh1(rng) -> dict:
         ("co_located", SingleDeviceTransport(cfg)),
     ):
         def fn(state, t=t):
-            st, info = t.replicate_pipeline(
-                state, wins, counts, 0, 1, alive, slow, term_floor=1,
-                allow_turnover=True,
+            st, infos = t.replicate_many(
+                state, wins, counts, 0, 1, alive, slow, repair=False,
+                term_floor=1,
             )
-            return st, info.commit_index
+            return st, infos.commit_index[-1]
 
         rows[name] = bench_scan(cfg, jax.jit(fn, donate_argnums=(0,)),
                                 reps=3)
@@ -2932,7 +2883,6 @@ def main(argv=None) -> None:
         dl.skipped.append("kernel_gates")
     else:
         ring_kernel_gate(rng)
-        pipeline_lap_gate(rng)
 
     # -- config 2: the headline ------------------------------------------
     cfg2 = RaftConfig()          # 3 replicas, 256 B, batch 1024

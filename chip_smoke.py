@@ -158,7 +158,7 @@ def run_one_chip(sizes: Sizes, seed: int) -> None:
     import numpy as np
 
     from raft_tpu import RaftConfig, RaftEngine
-    from raft_tpu.core.gates import pipeline_lap_gate, ring_kernel_gate
+    from raft_tpu.core.gates import ring_kernel_gate
     from raft_tpu.core.ring import pallas_interpret
 
     s = sizes
@@ -171,8 +171,7 @@ def run_one_chip(sizes: Sizes, seed: int) -> None:
                          transport="single")
         eng = RaftEngine(cfg)
         leader = eng.run_until_leader()
-        # one heartbeat round verifies the followers: the first full-ring
-        # chunk then rides the single-launch pipeline kernel
+        # one heartbeat round verifies the followers before the stream
         eng.run_for(cfg.heartbeat_period)
         applied = _ApplyHash()
         eng.register_apply(applied)
@@ -180,8 +179,8 @@ def run_one_chip(sizes: Sizes, seed: int) -> None:
         eng.submit_pipelined(_entries(data))
         _check(eng.commit_watermark == s.stream,
                f"commit watermark {eng.commit_watermark} != {s.stream}")
-        _check(watch.launches.get("single.pipeline", 0) >= 1,
-               "submit_pipelined never launched the pipeline kernel")
+        _check(watch.launches.get("single.replicate_many", 0) >= 1,
+               "submit_pipelined never launched the replication scan")
         _check(applied.index == s.stream,
                f"applied {applied.index} of {s.stream}")
         want = hashlib.sha256(data.tobytes())
@@ -248,8 +247,8 @@ def run_one_chip(sizes: Sizes, seed: int) -> None:
         eng.submit_pipelined(_entries(data))
         _check(eng.commit_watermark == s.ec_capacity,
                f"EC commit watermark {eng.commit_watermark}")
-        _check(watch.launches.get("single.pipeline", 0) >= 1,
-               "the EC lap never launched the pipeline kernel")
+        _check(watch.launches.get("single.replicate_many", 0) >= 1,
+               "the EC lap never launched the replication scan")
         down = min(r for r in range(cfg.rs_k) if r != leader)
         eng.fail(down)
         # one heartbeat round carries the leader's commit index to the
@@ -272,7 +271,6 @@ def run_one_chip(sizes: Sizes, seed: int) -> None:
         interpret = pallas_interpret()
         return {**ring_kernel_gate(rng, s.gate_capacity, s.batch,
                                    interpret=interpret),
-                **pipeline_lap_gate(rng, s.batch, interpret=interpret),
                 "passed": True}
 
     _phase("b_main_path", main_path)

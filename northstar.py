@@ -41,8 +41,9 @@ from raft_tpu.obs.compile import use_persistent_cache
 from raft_tpu.obs.profiling import device_seconds
 
 CHUNK_STEPS = 32     # steps per device dispatch. Each chunk is ONE
-#   kernel launch (core.step_pallas.steady_pipeline_tpu); the launch has
-#   a ~160 us fixed cost, so bigger chunks amortize better — but the
+#   compiled scan (core.step.scan_replicate, which runs the fused
+#   core.step_pallas.steady_scan_replicate_tpu on kernel-eligible
+#   shapes); bigger chunks amortize the launch better — but the
 #   per-chunk fidelity read-back can only serve entries still in the
 #   ring (log_capacity = CHUNK_STEPS * batch below), and rings past
 #   ~32k slots start paying HBM locality (~+2 us/step measured). 32 is
@@ -60,38 +61,19 @@ def run_device(
     p99_us, wall_s, method) with the hash over follower-read-back
     committed bytes. ``measure_latency=False`` skips the timing probes
     (byte-identity-only callers, e.g. the CI test)."""
-    from raft_tpu.core.ring import _pallas_ok
-
     comm = SingleDeviceComm(cfg.n_replicas)
-    if _pallas_ok(cfg.log_capacity, cfg.batch_size):
-        # the saturated chunk as ONE kernel launch (the launch-feasibility
-        # cond inside falls back to the per-step fused scan for the
-        # stream's partial final chunk)
-        from raft_tpu.core.ring import pallas_interpret
-        from raft_tpu.core.step_pallas import steady_pipeline_tpu
 
-        def _chunk(st, ps, cs):
-            st, info = steady_pipeline_tpu(
-                st, ps, cs, jnp.int32(0), jnp.int32(1),
-                jnp.ones(cfg.n_replicas, bool),
-                jnp.zeros(cfg.n_replicas, bool),
-                jnp.int32(0), jnp.int32(0), None, jnp.int32(1),
-                commit_quorum=cfg.commit_quorum,
-                interpret=pallas_interpret(),
-            )
-            return st, info
-    else:
-        def _chunk(st, ps, cs):
-            st, infos = scan_replicate(
-                comm, False, cfg.commit_quorum, False, st, ps, cs,
-                jnp.int32(0), jnp.int32(1),
-                jnp.ones(cfg.n_replicas, bool),
-                jnp.zeros(cfg.n_replicas, bool),
-                # single-term pipeline: every index is current-term, so
-                # the fused whole-step steady program serves
-                term_floor=1,
-            )
-            return st, jax.tree.map(lambda a: a[-1], infos)
+    def _chunk(st, ps, cs):
+        st, infos = scan_replicate(
+            comm, False, cfg.commit_quorum, False, st, ps, cs,
+            jnp.int32(0), jnp.int32(1),
+            jnp.ones(cfg.n_replicas, bool),
+            jnp.zeros(cfg.n_replicas, bool),
+            # single-term stream: every index is current-term, so the
+            # fused whole-step steady program serves
+            term_floor=1,
+        )
+        return st, jax.tree.map(lambda a: a[-1], infos)
 
     fn = jax.jit(_chunk, donate_argnums=(0,))
     B, E = cfg.batch_size, cfg.entry_bytes
@@ -190,7 +172,7 @@ def main():
     args = ap.parse_args()
     use_persistent_cache()
     # 3 replicas, 256 B entries, batch 1024 — the north star. The ring
-    # must hold one full pipeline chunk: the per-chunk fidelity read-back
+    # must hold one full chunk: the per-chunk fidelity read-back
     # (SHA over follower bytes) can only serve entries still in the ring,
     # so log_capacity >= CHUNK_STEPS * batch (a ~100 MB device ring).
     cfg = RaftConfig(log_capacity=CHUNK_STEPS * 1024)

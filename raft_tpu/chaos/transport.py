@@ -41,9 +41,9 @@ documented simulation framing (see ``read_linearizable``) — so message
 faults model data-plane loss, not control-plane partitions; use
 ``partition()`` for those.
 
-The wrapper deliberately does NOT expose ``replicate_pipeline``: the
-engine's eligibility gate then routes every chunk through the general
-scan path, keeping one code path under fault injection.
+``submit_pipelined`` chunks reach the base transport through
+``replicate_many``, so pipelined ingest runs under the same drop draws
+as the tick path (one draw per chunk).
 """
 
 from __future__ import annotations

@@ -88,19 +88,6 @@ class RaftConfig:
     prevote: bool = False
     check_quorum: bool = False
 
-    # --- pipelined-ingest chunk size (ring turnovers per launch) ---
-    # submit_pipelined's fast path runs a full ring of batches as ONE
-    # kernel launch. On an all-accept steady cluster the write-only
-    # turnover kernel is additionally legal across ring LAPS (every step
-    # commits before its slots are revisited), so a large backlog can
-    # ride a single launch spanning this many ring turnovers —
-    # amortizing launch and host-sync cost k-fold (docs/PERF.md round 5
-    # measured 1.13 B entries/s device-side at 8 laps). 1 = one ring per
-    # launch (the conservative default). Exactly two programs compile
-    # (1 lap and max laps) — the engine only takes the lapped shape when
-    # the backlog covers it entirely.
-    pipeline_max_laps: int = 1
-
     # --- multihost mirror desync guard ---
     # Every N-th control-plane decision (event-heap pop), fold the
     # decision and its observable outcome into a rolling digest and
@@ -304,8 +291,6 @@ class RaftConfig:
             raise ValueError("promote_max_lag must be >= 1 (or None)")
         if self.steady_dispatch not in ("auto", "off"):
             raise ValueError('steady_dispatch must be "auto" or "off"')
-        if self.pipeline_max_laps < 1:
-            raise ValueError("pipeline_max_laps must be >= 1")
         if self.fuse_k < 1:
             raise ValueError("fuse_k must be >= 1 (1 = fusion off)")
         if self.admission_max_writes is not None and self.admission_max_writes < 1:

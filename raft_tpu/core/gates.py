@@ -1,24 +1,18 @@
 """Kernel equivalence gates: the Pallas kernels byte-asserted against
 their reference formulations on the backend they run on.
 
-CI runs the kernels in interpret mode only, and two regimes are beyond
-what interpret mode can vouch for: the ring-write kernel's in-place
-modular-block writes, and the single-launch pipeline revisiting ring
-blocks within one ``pallas_call`` under input/output aliasing. These
-gates run the compiled kernels on the chip and compare every byte with
-the XLA formulation or the per-step fused scan. They raise
-``AssertionError`` on the first mismatch and never skip themselves: the
-caller decides where they run (``chip_smoke.py`` on the chip, ``bench.py``
-before its legs).
+CI runs the kernels in interpret mode only, and the ring-write kernel's
+in-place modular-block writes are beyond what interpret mode can vouch
+for. This gate runs the compiled kernel on the chip and compares every
+byte with the XLA formulation. It raises ``AssertionError`` on the first
+mismatch and never skips itself: the caller decides where it runs
+(``chip_smoke.py`` on the chip, ``bench.py`` before its legs).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-
-from raft_tpu.config import RaftConfig
-from raft_tpu.core.state import fold_batch, init_state
 
 
 def ring_kernel_gate(rng, capacity: int = 1 << 15, batch: int = 1024,
@@ -76,91 +70,3 @@ def ring_kernel_gate(rng, capacity: int = 1 << 15, batch: int = 1024,
         )
     return {"ring_gate_cases": len(cases), "ring_gate_capacity": C}
 
-
-def pipeline_lap_gate(rng, batch: int = 1024,
-                      interpret: bool = False) -> dict:
-    """The single-launch pipeline kernel in the ring-LAP regime (a
-    12-step flight over a ring of 4 batches revisits every destination block
-    within one launch) against the per-step fused scan, byte for byte:
-    the write-only turnover branch, the aliased pipeline on the same
-    all-accept flight, the aliased pipeline with a never-accepting slow
-    row, and the RS(5,3) lane geometry with in-kernel parity plus its
-    decode from a non-systematic row subset."""
-    from raft_tpu.core.step_pallas import (
-        steady_pipeline_tpu, steady_scan_replicate_tpu,
-    )
-
-    cfg = RaftConfig(batch_size=batch, log_capacity=4 * batch)  # T: 3 laps
-    T = 12
-    wins4 = jnp.stack([
-        jnp.asarray(fold_batch(rng.integers(
-            0, 256, (cfg.batch_size, cfg.entry_bytes), dtype=np.uint8
-        ), cfg.rows))
-        for _ in range(4)
-    ])
-    counts = jnp.full((T,), cfg.batch_size, jnp.int32)
-    xs = jnp.stack([wins4[t % 4] for t in range(T)])
-    cases = [
-        (np.zeros(3, bool), True),
-        (np.zeros(3, bool), False),
-        (np.array([False, False, True]), False),
-    ]
-    for slow, allow in cases:
-        args = (jnp.int32(0), jnp.int32(1), jnp.ones(3, bool),
-                jnp.asarray(slow), jnp.int32(0), jnp.int32(0), None,
-                jnp.int32(1))
-        st_s, _ = steady_scan_replicate_tpu(
-            init_state(cfg), xs, counts, *args, commit_quorum=None,
-            stack_infos=False, interpret=interpret,
-        )
-        st_p, _ = steady_pipeline_tpu(
-            init_state(cfg), wins4, counts, *args, commit_quorum=None,
-            allow_turnover=allow, interpret=interpret,
-        )
-        for f in ("term", "voted_for", "last_index", "commit_index",
-                  "match_index", "match_term", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-                err_msg=f"pipeline lap regime diverges: {f} "
-                        f"(slow={slow}, turnover={allow})",
-            )
-
-    from raft_tpu.ec.kernels import fold_data_lanes, parity_consts
-    from raft_tpu.ec.reconstruct import reconstruct
-    from raft_tpu.ec.rs import RSCode
-
-    ecfg = RaftConfig(n_replicas=5, entry_bytes=264, batch_size=batch,
-                      log_capacity=4 * batch, rs_k=3, rs_m=2,
-                      transport="single")
-    consts = parity_consts(5, 3)
-    raw = rng.integers(
-        0, 256, (T, ecfg.batch_size, ecfg.entry_bytes), dtype=np.uint8
-    )
-    ewins = jnp.stack([fold_data_lanes(jnp.asarray(raw[t]))
-                       for t in range(T)])
-    eargs = (jnp.int32(0), jnp.int32(1), jnp.ones(5, bool),
-             jnp.zeros(5, bool), jnp.int32(0), jnp.int32(0), None,
-             jnp.int32(1))
-    st_s, _ = steady_scan_replicate_tpu(
-        init_state(ecfg), ewins, counts, *eargs,
-        commit_quorum=ecfg.commit_quorum, stack_infos=False,
-        ec_consts=consts, interpret=interpret,
-    )
-    st_p, _ = steady_pipeline_tpu(
-        init_state(ecfg), ewins, counts, *eargs,
-        commit_quorum=ecfg.commit_quorum, ec_consts=consts,
-        interpret=interpret,
-    )
-    for f in ("last_index", "commit_index", "log_term", "log_payload"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-            err_msg=f"EC pipeline lap regime diverges: {f}",
-        )
-    hi = T * ecfg.batch_size
-    lo = hi - ecfg.log_capacity + 1
-    got = reconstruct(st_p, RSCode(5, 3), [1, 2, 4], lo, hi)
-    np.testing.assert_array_equal(
-        got, raw.reshape(-1, ecfg.entry_bytes)[-ecfg.log_capacity:],
-        err_msg="EC pipeline lap decode != raw bytes",
-    )
-    return {"lap_gate_cases": len(cases) + 1, "lap_gate_steps": T}

@@ -35,7 +35,7 @@ fault masks, given two invariants the engine maintains:
 
 The §5.3 conflict bit and the next-prev stash — the only places the
 resident kernel reads OTHER rows' ring content — are replaced by those
-closed forms (``local=True`` in ``core.step_pallas``'s kernel bodies; the
+closed forms (``local=True`` in ``core.step_pallas``'s kernel body; the
 data-plane geometry, merge, and quorum arithmetic are the very same
 code). The engine-level differential and chaos suites pin the invariants;
 ``tests/test_step_mesh.py`` pins this path byte-identical to the general
@@ -62,12 +62,8 @@ from raft_tpu.core.step_pallas import (
     _VV,
     _frontier_slots,
     _invoke,
-    _launch_feasibility,
     _mk_info,
     _params_and_masks,
-    _pick_br,
-    _run_pipeline,
-    _run_turnover,
 )
 
 # trace-time marker: the most recent fused-mesh entry point traced, so
@@ -192,27 +188,11 @@ def mesh_scan_replicate(
         state, leader, leader_term, term_floor, repair_floor,
         floor_prev_term, alive, slow, member, commit_quorum, ec, axis,
     )
-    final, infos = _scan_raw(
-        vecs0, prev0, s0, params, masks, state.log_term,
-        state.log_payload, payloads, counts, interpret, stack_infos,
-    )
-    state = _unpack_local(axis, final[0], final[1], final[2])
-    return state, (infos if stack_infos else final[5])
-
-
-def _scan_raw(vecs0, prev0, s0, params, masks, log_term, log_payload,
-              payloads, counts, interpret, stack_infos,
-              mk_payload=None):
-    """The scan over local fused steps on raw carries — shared by
-    ``mesh_scan_replicate`` and the pipeline's fallback branch (which
-    needs pytree-identical outputs across ``lax.cond`` branches)."""
     R = vecs0.shape[1]
 
     def body(carry, xs):
         vecs, lt, lp, s, prev_col = carry[:5]
         win, cnt = xs
-        if mk_payload is not None:
-            win = mk_payload(win)
         lp, lt, vecs, match_o, scal_o, next_prev = _invoke(
             s, jnp.int32(cnt).reshape(1, 1), prev_col, params, vecs,
             masks, win, lp, lt, interpret, local=True,
@@ -223,88 +203,11 @@ def _scan_raw(vecs0, prev0, s0, params, masks, log_term, log_payload,
             return carry, info
         return carry + (info,), None
 
-    carry0 = (vecs0, log_term, log_payload, s0, prev0)
+    carry0 = (vecs0, state.log_term, state.log_payload, s0, prev0)
     if not stack_infos:
         carry0 = carry0 + (_mk_info(
             jnp.zeros((1, R), jnp.int32), jnp.zeros((1, 4), jnp.int32)
         ),)
-    return lax.scan(body, carry0, (payloads, counts))
-
-
-def mesh_pipeline(
-    axis: str,
-    state: ReplicaState,
-    wins: jax.Array,                # i32[P, B, W] local window stack
-    counts: jax.Array,              # i32[T]
-    leader, leader_term, alive, slow, floor_prev_term, repair_floor,
-    member, term_floor,
-    commit_quorum: int | None = None,
-    ec: bool = False,
-    interpret: bool = False,
-    allow_turnover: bool = True,
-):
-    """T saturated steps as ONE per-device kernel launch — the resident
-    ``steady_pipeline_tpu``'s regimes (write-only full turnover >
-    aliased affine pipeline > per-step fused scan) on the mesh layout.
-    The launch-feasibility predicate is the SAME shared code
-    (``_launch_feasibility``) evaluated on the gathered (replicated)
-    plane, so every device takes identical branches and the engine's
-    host gate keeps implying it; after the two launch gathers the whole
-    flight is communication-free (module doc)."""
-    global LAST_DISPATCH
-    LAST_DISPATCH = "pipeline"
-    cap = state.capacity
-    R = alive.shape[0]
-    P, B, W = wins.shape
-    T = counts.shape[0]
-    BR = _pick_br(B, cap)
-    G = B // BR + 1
-    CB = cap // BR
-    WB = B // BR
-    vecs, prev0, s0, params, masks = _plane_and_params(
-        state, leader, leader_term, term_floor, repair_floor,
-        floor_prev_term, alive, slow, member, commit_quorum, ec, axis,
-    )
-    cnts = counts.astype(jnp.int32).reshape(1, T)
-    feasible, accept0 = _launch_feasibility(
-        vecs, masks, params, prev0, counts, s0, BR, B, R, leader,
-        leader_term, repair_floor, floor_prev_term,
-    )
-
-    def run_scan(st):
-        carry, _ = _scan_raw(
-            vecs, prev0, s0, params, masks, st.log_term, st.log_payload,
-            jnp.arange(T), counts, interpret, False,
-            mk_payload=lambda t: lax.dynamic_index_in_dim(
-                wins, t % P, 0, keepdims=False
-            ),
-        )
-        return (carry[2], carry[1], carry[0]), carry[5]
-
-    def run_pipeline(st):
-        return _run_pipeline(
-            st, wins, cnts, s0, prev0, params, vecs, masks,
-            BR, G, CB, WB, P, T, cap, W, W, R, None, interpret,
-            local=True,
-        )
-
-    if allow_turnover and T * B >= cap:
-        all_accept = feasible & jnp.all(accept0)
-
-        def run_turnover(st):
-            return _run_turnover(
-                st, wins, s0, params, vecs, BR, CB, WB, P, T, cap,
-                W, W, R, None, interpret, local=True,
-            )
-
-        def run_general(st):
-            return lax.cond(feasible, run_pipeline, run_scan, st)
-
-        (lp, lt, vecs_o), info = lax.cond(
-            all_accept, run_turnover, run_general, state
-        )
-    else:
-        (lp, lt, vecs_o), info = lax.cond(
-            feasible, run_pipeline, run_scan, state
-        )
-    return _unpack_local(axis, vecs_o, lt, lp), info
+    final, infos = lax.scan(body, carry0, (payloads, counts))
+    state = _unpack_local(axis, final[0], final[1], final[2])
+    return state, (infos if stack_infos else final[5])

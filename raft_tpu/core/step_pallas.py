@@ -92,8 +92,7 @@ def _pick_br(B: int, C: int) -> int:
 
 def _encode_parity_lanes(src, pconsts, BR, W):
     """Append the RS parity lane blocks to a (BR, k*W) data-lane window
-    using the packed-i32 GF(2^8) multiply — THE shared restatement used
-    by every kernel variant (per-step, pipeline, turnover)."""
+    using the packed-i32 GF(2^8) multiply."""
     m_par, k_data = pconsts.shape[0], pconsts.shape[1]
     parts = [src]
     for p in range(m_par):
@@ -124,12 +123,6 @@ def _mul_const_packed(x, c_bits):
     return acc
 
 
-# NOTE: _steady_kernel and _steady_pipeline_kernel are TWIN BODIES — the
-# pipeline variant re-states this kernel with SMEM-scratch state and a
-# per-step geometry guard. A change to the merge, conflict check, parity
-# encode, or quorum logic must land in BOTH; tests/test_steady_fused.py
-# pins each against the general XLA formulation and against each other.
-#
 # ``local`` (static) selects the MESH data plane (core.step_mesh): the
 # scalar core still simulates ALL L(=R) rows from the gathered state
 # vectors — replicated SPMD work, identical on every device — but the
@@ -644,597 +637,3 @@ def steady_scan_replicate_tpu(
     final, infos = jax.lax.scan(body, carry0, (payloads, counts))
     state = _unpack(final[0], final[1], final[2])
     return state, (infos if stack_infos else final[5])
-
-
-# ---------------------------------------------------------------- pipeline
-# The saturated pipeline as ONE kernel launch: a (T, G) grid runs T full
-# steady steps back to back, state vectors and masks living in SMEM
-# scratch for the whole flight. Legal because a saturated pipeline's
-# window start slot is AFFINE in t — every step ingests a full batch, so
-# s_t = (s_0 + t*B) % C and (B % BR == 0) even keeps the sub-block
-# misalignment constant — which is exactly what a BlockSpec index map can
-# express. A step that ingests less than a full batch (ring backpressure,
-# deposed leader) breaks the affine geometry; the kernel detects the
-# mismatch in its per-step prologue and degrades every remaining step to
-# a masked no-op write-back (the committed prefix stays correct, and the
-# caller sees the shortfall in the final commit index). The per-scan-step
-# costs this removes — loop bookkeeping, operand staging, launch/gap
-# overhead (~1 us/step measured) — are the last schedulable overhead of
-# the scan formulation.
-
-def _steady_pipeline_kernel(BR: int, C: int, L: int, G: int, P: int,
-                            pconsts, local, s0_ref,
-                            counts_ref, prev0_ref, par_ref, vecs0_ref,
-                            msks_ref, wins_ref, bufp_ref, buft_ref,
-                            outp_ref, outt_ref, vec_o, match_o, scal_o,
-                            prevp_ref, msk_ref, vec_scr, prevc_scr,
-                            flag_scr):
-    t = pl.program_id(0)
-    i = pl.program_id(1)
-    T = pl.num_programs(0)
-    s0 = s0_ref[0]
-    leader = par_ref[0, _LEADER]
-    lterm = par_ref[0, _LTERM]
-    M = outp_ref.shape[1]
-    W = M if local else M // L
-    B = BR * (G - 1)
-    off = s0 % BR                       # constant: B % BR == 0
-    s_t = (s0 + t * B) % C              # the map's assumed start slot
-    legit = lterm >= 1
-
-    @pl.when((t == 0) & (i == 0))
-    def _init():
-        for v in range(6):
-            for l in range(L):
-                vec_scr[v, l] = vecs0_ref[v, l]
-        for l in range(L):
-            prevc_scr[l, 0] = prev0_ref[l, 0]
-        flag_scr[0, 0] = 1              # affine geometry still valid
-
-    # ---- per-step prologue (i == 0) --------------------------------------
-    @pl.when(i == 0)
-    def _prologue():
-        last0_l = vec_scr[_VL, 0]
-        commit0_l = vec_scr[_VC, 0]
-        term0_l = vec_scr[_VT, 0]
-        for l in range(1, L):
-            pick = leader == l
-            last0_l = jnp.where(pick, vec_scr[_VL, l], last0_l)
-            commit0_l = jnp.where(pick, vec_scr[_VC, l], commit0_l)
-            term0_l = jnp.where(pick, vec_scr[_VT, l], term0_l)
-        leader_current = legit & (term0_l <= lterm)
-        room = C - (last0_l - commit0_l)
-        count = jnp.where(
-            leader_current,
-            jnp.minimum(jnp.clip(counts_ref[0, t], 0, B),
-                        jnp.maximum(room, 0)),
-            0,
-        )
-        ws = last0_l + 1
-        # geometry guard: the block maps assume ws lands at s_t; a prior
-        # short step breaks that for good
-        flag_scr[0, 0] &= ((ws - 1) % C == s_t).astype(jnp.int32)
-        count = jnp.where(flag_scr[0, 0] != 0, count, 0)
-        leader_last = last0_l + count
-        msk_ref[_FRS, _F_COUNT] = count
-        msk_ref[_FRS, _F_WS] = ws
-        msk_ref[_FRS, _F_LCUR] = leader_current.astype(jnp.int32)
-        prev_ts = [prevc_scr[l, 0] for l in range(L)]
-        ring_prev = prev_ts[0]
-        for l in range(1, L):
-            ring_prev = jnp.where(leader == l, prev_ts[l], ring_prev)
-        prev_term = jnp.where(
-            ws - 1 < par_ref[0, _RFLOOR], par_ref[0, _FPT], ring_prev
-        )
-        prev_term = jnp.where(ws == 1, 0, prev_term)
-        for l in range(L):
-            has_prev = (ws == 1) | (
-                (vec_scr[_VL, l] >= ws - 1) & (prev_ts[l] == prev_term)
-            )
-            heard = (msks_ref[_MAL, l] != 0) & legit & \
-                (lterm >= vec_scr[_VT, l])
-            ingest = (leader == l) & (msk_ref[_FRS, _F_LCUR] != 0)
-            m0 = jnp.where(vec_scr[_VMT, l] == lterm, vec_scr[_VMI, l], 0)
-            m0 = jnp.where(ingest & (count > 0), leader_last, m0)
-            acc = (heard & (msks_ref[_MSL, l] == 0) & has_prev) | ingest
-            acc &= count > 0            # degraded mode: touch nothing
-            msk_ref[_ACC, l] = acc.astype(jnp.int32)
-            msk_ref[_HEARD, l] = heard.astype(jnp.int32)
-            msk_ref[_MEFF, l] = m0
-            msk_ref[_MM, l] = 0
-
-    count = msk_ref[_FRS, _F_COUNT]
-    ws = msk_ref[_FRS, _F_WS]
-
-    # ---- window merge (identical geometry to the per-step kernel) --------
-    r = jax.lax.broadcasted_iota(jnp.int32, (BR, M), 0)
-    jj = BR * i - off + r
-    if local:
-        myr = par_ref[0, _MYROW]
-        acc_my = msk_ref[_ACC, 0]
-        for l in range(1, L):
-            acc_my = jnp.where(myr == l, msk_ref[_ACC, l], acc_my)
-        sel = (jj >= 0) & (jj < count) & (acc_my != 0)
-    else:
-        lane_rep = jax.lax.broadcasted_iota(jnp.int32, (BR, M), 1) // W
-        lanes = (lane_rep == 0) & (msk_ref[_ACC, 0] != 0)
-        for l in range(1, L):
-            lanes |= (lane_rep == l) & (msk_ref[_ACC, l] != 0)
-        sel = (jj >= 0) & (jj < count) & lanes
-    win = wins_ref[0]
-    val2 = jnp.concatenate([prevp_ref[:], win], axis=0)
-    src = pltpu.roll(val2, off - BR, 0)[:BR]
-    if pconsts is not None:
-        src = _encode_parity_lanes(src, pconsts, BR, W)
-    outp_ref[:] = jnp.where(sel, src, bufp_ref[:])
-    prevp_ref[:] = win
-
-    c1 = jax.lax.broadcasted_iota(jnp.int32, (1, BR), 1)
-    jt1 = BR * i - off + c1
-    valid1 = (jt1 >= 0) & (jt1 < count)
-    curt = buft_ref[:]
-    if local:
-        # local row's term ring only; conflict bit + next-prev are
-        # closed-form (see _steady_kernel NOTE)
-        outt_ref[:] = jnp.where(valid1 & (acc_my != 0), lterm, curt)
-    else:
-        rows_t = []
-        for l in range(L):
-            cur_l = curt[l:l + 1, :]
-            rows_t.append(jnp.where(
-                valid1 & (msk_ref[_ACC, l] != 0), lterm, cur_l
-            ))
-            mm_row = valid1 & (ws + jt1 <= vec_scr[_VL, l]) & \
-                (cur_l != lterm)
-            msk_ref[_MM, l] |= jnp.max(jnp.where(mm_row, 1, 0))
-        outt_ref[:] = jnp.concatenate(rows_t, axis=0)
-
-        # stash the next step's prev-term column while its block is in
-        # VMEM
-        q = (s_t + count - 1) % C
-        d = ((s_t // BR) + i) % (C // BR)
-
-        @pl.when((count > 0) & (d == q // BR))
-        def _stash_next_prev():
-            sel_q = c1 == q % BR
-            for l in range(L):
-                prevc_scr[l, 0] = jnp.sum(jnp.where(sel_q, rows_t[l], 0))
-
-    # ---- per-step epilogue (i == G-1) ------------------------------------
-    @pl.when(i == G - 1)
-    def _epilogue():
-        leader_current = msk_ref[_FRS, _F_LCUR] != 0
-        we = ws + count - 1
-        matches = []
-        meffs = []
-        heards = []
-        for l in range(L):
-            acc = msk_ref[_ACC, l] != 0
-            mm = msk_ref[_MM, l] != 0
-            heard = msk_ref[_HEARD, l] != 0
-            m0 = msk_ref[_MEFF, l]
-            last0 = vec_scr[_VL, l]
-            if local:
-                # closed form (_steady_kernel NOTE); acc already implies
-                # count > 0 in the pipeline prologue
-                vec_scr[_VL, l] = jnp.where(acc, we, last0)
-                prevc_scr[l, 0] = jnp.where(
-                    count > 0,
-                    jnp.where(acc, lterm, jnp.int32(-1)),
-                    prevc_scr[l, 0],
-                )
-            else:
-                vec_scr[_VL, l] = jnp.where(
-                    acc,
-                    jnp.where(mm, jnp.maximum(we, ws - 1),
-                              jnp.maximum(last0, we)),
-                    last0,
-                )
-            m1 = jnp.where(acc, jnp.maximum(m0, we), m0)
-            meffs.append(m1)
-            heards.append(heard)
-            matches.append(jnp.where(msks_ref[_MAK, l] != 0, m1, 0))
-        cand = jnp.int32(0)
-        for l in range(L):
-            cnt = jnp.int32(0)
-            for j in range(L):
-                cnt += (matches[j] >= matches[l]).astype(jnp.int32)
-            cand = jnp.maximum(
-                cand, jnp.where(cnt >= par_ref[0, _QUORUM], matches[l], 0)
-            )
-        commit_ok = legit & (cand >= 1) & (cand >= par_ref[0, _TFLOOR])
-        lcommit = vec_scr[_VC, 0]
-        for l in range(1, L):
-            lcommit = jnp.where(leader == l, vec_scr[_VC, l], lcommit)
-        g_commit = jnp.where(
-            commit_ok, jnp.maximum(lcommit, cand), lcommit
-        )
-        max_term = jnp.int32(0)
-        for l in range(L):
-            heard = heards[l]
-            ingest = (leader == l) & leader_current
-            t0 = vec_scr[_VT, l]
-            adopt = heard & (lterm > t0)
-            t1 = jnp.where(heard, jnp.maximum(t0, lterm), t0)
-            vec_scr[_VT, l] = t1
-            vec_scr[_VV, l] = jnp.where(adopt, NO_VOTE, vec_scr[_VV, l])
-            my_commit = jnp.where(
-                leader == l, g_commit, jnp.minimum(g_commit, meffs[l])
-            )
-            vec_scr[_VC, l] = jnp.where(
-                (heard & (msks_ref[_MSL, l] == 0)) | ingest,
-                jnp.maximum(vec_scr[_VC, l], my_commit),
-                vec_scr[_VC, l],
-            )
-            vec_scr[_VMI, l] = jnp.where(heard | ingest, meffs[l],
-                                         vec_scr[_VMI, l])
-            vec_scr[_VMT, l] = jnp.where(heard | ingest, lterm,
-                                         vec_scr[_VMT, l])
-            max_term = jnp.maximum(
-                max_term, jnp.where(msks_ref[_MAL, l] != 0, t1, 0)
-            )
-
-        @pl.when(t == T - 1)
-        def _finalize():
-            for v in range(6):
-                for l in range(L):
-                    vec_o[v, l] = vec_scr[v, l]
-            for l in range(L):
-                match_o[0, l] = matches[l]
-            scal_o[0, 0] = g_commit
-            scal_o[0, 1] = max_term
-            scal_o[0, 2] = count
-            scal_o[0, 3] = (ws - 1 + count) % C
-
-
-def _launch_feasibility(vecs, masks, params, prev0, counts, s0, BR, B, L,
-                        leader, leader_term, repair_floor,
-                        floor_prev_term):
-    """The single-launch pipeline's launch-feasibility predicate and the
-    launch-time accept set (shared by the resident ``steady_pipeline_tpu``
-    and the mesh ``core.step_mesh`` pipeline so the two can never drift).
-    All inputs are replicated values; under ``shard_map`` every device
-    computes the identical decision."""
-    last0_l = vecs[_VL, leader]
-    commit0_l = vecs[_VC, leader]
-    term0_l = vecs[_VT, leader]
-    lterm = jnp.int32(leader_term)
-    leader_current = (lterm >= 1) & (term0_l <= lterm)
-    ws0 = last0_l + 1
-    prev_term = jnp.where(
-        ws0 - 1 < jnp.int32(repair_floor), jnp.int32(floor_prev_term),
-        prev0[leader, 0],
-    )
-    prev_term = jnp.where(ws0 == 1, 0, prev_term)
-    rows = jnp.arange(L)
-    accept0 = (
-        (masks[_MAL] != 0) & (masks[_MSL] == 0) & (masks[_MAK] != 0)
-        & (lterm >= vecs[_VT]) & (vecs[_VL] == last0_l)
-        & ((ws0 == 1) | (prev0[:, 0] == prev_term))
-    ) | ((rows == jnp.int32(leader)) & (masks[_MAK] != 0))
-    #     ^ the leader's own match counts toward the quorum only when it
-    #       is inside the ack mask (a departing non-member leader's row
-    #       is zeroed by the kernel's _MAK gate — counting it here would
-    #       declare a flight feasible that can never commit)
-    quorum = params[0, _QUORUM]
-    feasible = (
-        leader_current
-        & (commit0_l == last0_l)
-        & (s0[0] % BR == 0)
-        & jnp.all(counts == B)
-        & (jnp.sum(accept0.astype(jnp.int32)) >= quorum)
-    )
-    return feasible, accept0
-
-
-def steady_pipeline_tpu(
-    state: ReplicaState,
-    wins: jax.Array,                # i32[P, B, Mk] window stack; step t
-    #                                 reads wins[t % P] (P=1: one window
-    #                                 re-ingested every step — the bench's
-    #                                 constant-payload saturation mode)
-    counts: jax.Array,              # i32[T]
-    leader, leader_term, alive, slow, floor_prev_term, repair_floor,
-    member, term_floor,
-    commit_quorum: int | None = None,
-    ec: bool = False,               # STATIC: see steady_replicate_step_tpu
-    interpret: bool = False,
-    ec_consts=None,
-    allow_turnover: bool = True,    # STATIC: compile the write-only
-    #                                 full-turnover branch (see below).
-    #                                 Callers that statically know a row
-    #                                 cannot accept (an induced-slow mask,
-    #                                 membership headroom) pass False so
-    #                                 the compiled program stays a simple
-    #                                 two-way cond — a third branch taxes
-    #                                 the aliased path ~2 us/step through
-    #                                 output-buffer unification.
-):
-    """T saturated steady steps as ONE pallas_call (module comment above).
-    Returns (state, final RepInfo).
-
-    **Launch feasibility.** The affine block maps are only sound when
-    every step ingests a FULL batch, which is decidable at launch (the
-    fault masks are constants for the whole flight): the start slot must
-    be BR-aligned, every count must be B, the start state fully
-    committed, and the launch-time accept set (caught-up, reachable,
-    non-slow members whose prev entry matches — plus the leader) must
-    meet the commit quorum; by induction those rows then accept and
-    commit every window. When the predicate fails, a ``lax.cond``
-    routes the call to the per-step fused scan instead — identical
-    semantics, one launch per step. (The kernel additionally carries a
-    geometry flag that no-ops any step whose window start disagrees
-    with the maps — defense in depth; revisit write-backs under that
-    flag are only guaranteed benign on real hardware, which is why the
-    launch predicate, not the flag, is the correctness story.)"""
-    cap = state.capacity
-    L = state.term.shape[0]
-    P, B, Mk = wins.shape
-    T = counts.shape[0]
-    M = state.log_payload.shape[1]
-    if (Mk != M) != (ec_consts is not None):
-        raise ValueError(
-            f"window lanes {Mk} vs payload lanes {M}: data-lane-only "
-            "windows require ec_consts, full-lane windows must not"
-        )
-    BR = _pick_br(B, cap)
-    G = B // BR + 1
-    CB = cap // BR
-    WB = B // BR
-    vecs = _pack(state)
-    params, masks = _params_and_masks(
-        leader, leader_term, term_floor, repair_floor, floor_prev_term,
-        alive, slow, member, commit_quorum, L,
-        ec=ec or ec_consts is not None,
-    )
-    s0, prev0 = _start_slot_and_prev(vecs, state.log_term, leader, cap, L)
-    cnts = counts.astype(jnp.int32).reshape(1, T)
-
-    # ---- launch feasibility (see docstring) ------------------------------
-    feasible, accept0 = _launch_feasibility(
-        vecs, masks, params, prev0, counts, s0, BR, B, L, leader,
-        leader_term, repair_floor, floor_prev_term,
-    )
-
-    def run_scan(state):
-        # per-step fused scan over the same windows (wins[t % P])
-        return steady_scan_replicate_tpu(
-            state, jnp.arange(T), counts, leader, leader_term, alive,
-            slow, floor_prev_term, repair_floor, member, term_floor,
-            commit_quorum=commit_quorum, ec=ec, interpret=interpret,
-            mk_payload=lambda t: jax.lax.dynamic_index_in_dim(
-                wins, t % P, 0, keepdims=False
-            ),
-            stack_infos=False, ec_consts=ec_consts,
-        )
-
-    def run_pipeline(state):
-        return _run_pipeline(
-            state, wins, cnts, s0, prev0, params, vecs, masks,
-            BR, G, CB, WB, P, T, cap, M, Mk, L, ec_consts, interpret,
-        )
-
-    if allow_turnover and T * B >= cap:
-        # Full-turnover regime: when EVERY row accepts (so nothing
-        # anywhere needs preserving) the flight runs the write-only
-        # kernel — no ring reads, no aliasing. accept0 over ALL rows
-        # automatically excludes headroom configs (spare rows' lanes
-        # would otherwise be left as garbage in the fresh buffers). The
-        # fallback nests the general two-way dispatch: measured on v5e
-        # the turnover branch runs ~1.5 us/step FASTER with this nesting
-        # than with a flat turnover-vs-scan cond (XLA's buffer unification
-        # works out better), while a caller who statically expects the
-        # general regime (induced-slow masks, headroom spares) passes
-        # allow_turnover=False and gets the plain two-way program — the
-        # nesting taxes the ALIASED branch ~2 us/step when taken.
-        all_accept = feasible & jnp.all(accept0)
-
-        def run_turnover(state):
-            return _run_turnover(
-                state, wins, s0, params, vecs, BR, CB, WB, P, T, cap,
-                M, Mk, L, ec_consts, interpret,
-            )
-
-        def run_general(state):
-            return jax.lax.cond(feasible, run_pipeline, run_scan, state)
-
-        return jax.lax.cond(all_accept, run_turnover, run_general, state)
-
-    return jax.lax.cond(feasible, run_pipeline, run_scan, state)
-
-
-def _run_pipeline(state, wins, cnts, s0, prev0, params, vecs, masks,
-                  BR, G, CB, WB, P, T, cap, M, Mk, L, ec_consts,
-                  interpret, local=False):
-    TL = state.log_term.shape[0]         # 1 in local (mesh) mode
-
-    def smem(shape):
-        return pl.BlockSpec(shape, lambda t, i, m: (0,) * len(shape),
-                            memory_space=pltpu.SMEM)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T, G),
-        in_specs=[
-            smem((1, T)),
-            smem((L, 1)),
-            smem((1, _NPARAMS)),
-            smem((6, L)),
-            smem((3, L)),
-            pl.BlockSpec((1, BR, Mk),
-                         lambda t, i, m: (t % P, jnp.clip(i, 0, WB - 1), 0)),
-            pl.BlockSpec(
-                (BR, M),
-                lambda t, i, m: (((m[0] // BR) + t * WB + i) % CB, 0),
-            ),
-            pl.BlockSpec(
-                (TL, BR),
-                lambda t, i, m: (0, ((m[0] // BR) + t * WB + i) % CB),
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (BR, M),
-                lambda t, i, m: (((m[0] // BR) + t * WB + i) % CB, 0),
-            ),
-            pl.BlockSpec(
-                (TL, BR),
-                lambda t, i, m: (0, ((m[0] // BR) + t * WB + i) % CB),
-            ),
-            smem((6, L)),
-            smem((1, L)),
-            smem((1, 4)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((BR, Mk), jnp.int32),
-            pltpu.SMEM((5, max(L, 3)), jnp.int32),
-            pltpu.SMEM((6, L), jnp.int32),
-            pltpu.SMEM((L, 1), jnp.int32),
-            pltpu.SMEM((1, 1), jnp.int32),
-        ],
-    )
-    outs = pl.pallas_call(
-        functools.partial(_steady_pipeline_kernel, BR, cap, L, G, P,
-                          ec_consts, local),
-        name="raft_pipeline",
-        out_shape=[
-            jax.ShapeDtypeStruct((cap, M), state.log_payload.dtype),
-            jax.ShapeDtypeStruct((TL, cap), state.log_term.dtype),
-            jax.ShapeDtypeStruct((6, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, 4), jnp.int32),
-        ],
-        grid_spec=grid_spec,
-        # operands after the prefetch arg: cnts, prev0, params, vecs,
-        # masks, wins, buf_p=#7, buf_t=#8
-        input_output_aliases={7: 0, 8: 1},
-        interpret=interpret,
-    )(s0, cnts, prev0, params, vecs, masks, wins,
-      state.log_payload, state.log_term)
-    log_payload, log_term, vec_o, match_o, scal_o = outs
-    if local:
-        return (log_payload, log_term, vec_o), _mk_info(match_o, scal_o)
-    return _unpack(vec_o, log_term, log_payload), _mk_info(match_o, scal_o)
-
-
-# --------------------------------------------------------- full turnover
-# The strongest regime of all: when EVERY row accepts every window (the
-# all-accept steady pipeline) and the flight turns the whole ring over
-# (T*B >= C), the merge preserves nothing — every block of both rings is
-# fully overwritten, the §5.3 conflict check is provably zero (windows
-# sit strictly beyond every caught-up row's tail), and the kernel needs
-# NO ring inputs and NO aliasing: write-only outputs into fresh buffers.
-# That removes the ring-read third of the HBM traffic — and, as a bonus,
-# the absence of aliased inputs makes interpret mode faithful even in
-# the revisit regime, so CI can pin this variant across ring laps.
-
-
-def _turnover_kernel(BR: int, C: int, L: int, G: int, P: int, pconsts,
-                     local, s0_ref, par_ref, vecs0_ref,
-                     wins_ref, outp_ref, outt_ref, vec_o, scal_o,
-                     vec_scr):
-    t = pl.program_id(0)
-    i = pl.program_id(1)
-    T = pl.num_programs(0)
-    lterm = par_ref[0, _LTERM]
-    M = outp_ref.shape[1]
-    W = M if local else M // L
-    B = BR * G
-
-    @pl.when((t == 0) & (i == 0))
-    def _init():
-        for v in range(6):
-            for l in range(L):
-                vec_scr[v, l] = vecs0_ref[v, l]
-
-    # window write: every lane of every row, unconditionally (in local
-    # mode the buffers hold one row's lanes; the all-accept predicate
-    # that admitted this kernel covers the local row too)
-    src = wins_ref[0]
-    if pconsts is not None:
-        src = _encode_parity_lanes(src, pconsts, BR, W)
-    outp_ref[:] = src
-    outt_ref[:] = jnp.full((1 if local else L, BR), lterm, jnp.int32)
-
-    # per-step epilogue: with all rows accepting a full window, the
-    # bookkeeping is closed-form — same formulas as the general program
-    # under the launch predicate (commit_ok from term_floor/legit kept
-    # for exactness)
-    @pl.when(i == G - 1)
-    def _epilogue():
-        we = vec_scr[_VL, 0] + B          # all rows share one tail here
-        legit = lterm >= 1
-        commit_ok = legit & (we >= 1) & (we >= par_ref[0, _TFLOOR])
-        for l in range(L):
-            t0 = vec_scr[_VT, l]
-            adopt = lterm > t0
-            vec_scr[_VT, l] = jnp.maximum(t0, lterm)
-            vec_scr[_VV, l] = jnp.where(adopt, NO_VOTE, vec_scr[_VV, l])
-            vec_scr[_VL, l] = we
-            vec_scr[_VMI, l] = we
-            vec_scr[_VMT, l] = lterm
-            vec_scr[_VC, l] = jnp.where(
-                commit_ok, we, vec_scr[_VC, l]
-            )
-
-        @pl.when(t == T - 1)
-        def _finalize():
-            for v in range(6):
-                for l in range(L):
-                    vec_o[v, l] = vec_scr[v, l]
-            scal_o[0, 0] = vec_scr[_VC, 0]
-            scal_o[0, 1] = jnp.maximum(vec_scr[_VT, 0], lterm)
-            scal_o[0, 2] = B
-            scal_o[0, 3] = we % C        # next window start slot
-
-
-def _run_turnover(state, wins, s0, params, vecs, BR, CB, WB, P, T, cap,
-                  M, Mk, L, ec_consts, interpret, local=False):
-    G = WB                               # off == 0: no overlap block
-    TL = state.log_term.shape[0]         # 1 in local (mesh) mode
-
-    def smem(shape):
-        return pl.BlockSpec(shape, lambda t, i, m: (0,) * len(shape),
-                            memory_space=pltpu.SMEM)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T, G),
-        in_specs=[
-            smem((1, _NPARAMS)),
-            smem((6, L)),
-            pl.BlockSpec((1, BR, Mk),
-                         lambda t, i, m: (t % P, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (BR, M),
-                lambda t, i, m: (((m[0] // BR) + t * WB + i) % CB, 0),
-            ),
-            pl.BlockSpec(
-                (TL, BR),
-                lambda t, i, m: (0, ((m[0] // BR) + t * WB + i) % CB),
-            ),
-            smem((6, L)),
-            smem((1, 4)),
-        ],
-        scratch_shapes=[pltpu.SMEM((6, L), jnp.int32)],
-    )
-    outs = pl.pallas_call(
-        functools.partial(_turnover_kernel, BR, cap, L, G, P, ec_consts,
-                          local),
-        name="raft_turnover",
-        out_shape=[
-            jax.ShapeDtypeStruct((cap, M), state.log_payload.dtype),
-            jax.ShapeDtypeStruct((TL, cap), state.log_term.dtype),
-            jax.ShapeDtypeStruct((6, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, 4), jnp.int32),
-        ],
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(s0, params, vecs, wins)
-    log_payload, log_term, vec_o, scal_o = outs
-    match_o = vec_o[_VMI][None, :]       # all-accept: match == new tail
-    if local:
-        return (log_payload, log_term, vec_o), _mk_info(match_o, scal_o)
-    return _unpack(vec_o, log_term, log_payload), _mk_info(match_o, scal_o)

@@ -66,18 +66,6 @@ CANDIDATE = "candidate"
 LEADER = "leader"
 
 
-def _pipeline_backend_ok() -> bool:
-    """The single-launch pipeline chunk runs on REAL hardware only —
-    deliberately stricter than ``ring._pallas_ok``: an engine chunk spans
-    the whole ring, so the flight always revisits destination blocks,
-    which interpret mode cannot model under in-place aliasing (bench.py's
-    lap gate asserts the regime on hardware; CI covers the engine gate
-    and bookkeeping through a transport shim that patches this hook)."""
-    import jax
-
-    return jax.default_backend() == "tpu"
-
-
 class LinearizableReadRefused(Exception):
     # deliberately NOT a RuntimeError: ReplicatedKV.linearizable_get's
     # other failure mode (apply stream paused behind an archive gap)
@@ -708,23 +696,20 @@ class RaftEngine:
         self._dev_counters_folded = counters
 
     def _dev_pre_chunk(self):
-        """Pre-capture the scalars chunk recording needs (term / commit
-        / last vectors) BEFORE a pipelined launch: ``replicate_pipeline``
-        donates the state buffers, so the old values must be copied out
-        first. None when the device plane is detached."""
+        """The pre-launch vectors chunk recording needs (term / commit /
+        last), taken BEFORE a ``submit_pipelined`` chunk's
+        ``replicate_many`` replaces ``self.state``. None when the device
+        plane is detached."""
         if self._dev_ring is None:
             return None
-        if not hasattr(self, "_dev_pre_jit"):
-            self._dev_pre_jit = jax.jit(
-                lambda s: (s.term, s.commit_index, s.last_index)
-            )
-        return self._dev_pre_jit(self.state)
+        s = self.state
+        return s.term, s.commit_index, s.last_index
 
     def _dev_record_chunk(self, pre, info, r: int, term: int,
                           ticks: int) -> None:
-        """Chunk-granularity device recording for the pipelined launches
-        (``submit_pipelined``): the fused pipeline kernel cannot carry
-        the per-step ring, so the chunk records its AGGREGATE transition
+        """Chunk-granularity device recording for ``submit_pipelined``:
+        the chunk's scan (``replicate_many``) carries no event ring, so
+        the chunk records its AGGREGATE transition
         — one commit-advance event (exactly mirroring the ONE host
         nodelog commit line each chunk produces via ``_advance_commit``)
         plus term adoptions, step-down evidence and counter deltas. The
@@ -942,12 +927,10 @@ class RaftEngine:
         periodically" design SURVEY.md §7 hard part 1 calls for. A chunk is
         as many full batches as are *guaranteed* ring room before the scan
         starts (commits inside the scan free more; the bound is
-        conservative, never lossy) — EXCEPT on the verified all-accept
-        fast path with ``cfg.pipeline_max_laps > 1``, where a chunk may
-        span several ring turnovers in one launch: there the turnover
-        kernel commits every step before its slots are revisited, so
-        room is created exactly as it is consumed (and the host buffers
-        the whole chunk's bytes for the archive regardless).
+        conservative, never lossy). Each chunk is one ``replicate_many``
+        launch: a one-step scan when the chunk fits in one batch and the
+        lap's padded steps would heal no follower, otherwise a scan over
+        the whole ring lap (T = log_capacity // batch_size steps).
 
         Requires a current leader. Returns the entries' sequence numbers;
         durability reporting matches ``submit`` (leadership loss mid-chunk
@@ -1018,14 +1001,9 @@ class RaftEngine:
                         # rest queued
                         break
                     take = min(len(pending), steps * B)
-                    # The lap decision is taken at T_ring: the pipeline
-                    # kernel's geometry is certified for whole laps only.
                     T = T_ring
-                    eligible = self._pipeline_eligible(
-                        r, take, T, leader_last, eff
-                    )
                     heals = self._repair_program() and not cfg.ec_enabled
-                    if not eligible and take <= B and not heals:
+                    if take <= B and not heals:
                         # A chunk that fits in one batch runs one scan
                         # step, not a lap padded with zero-count
                         # (heartbeat) steps, when those steps would carry
@@ -1043,30 +1021,6 @@ class RaftEngine:
                         # windows, removes the cliff at one batch. Each
                         # scan length is its own XLA program.
                         T = 1
-                    # ALL rows in the gate's verified accept set — the
-                    # kernel's own turnover predicate evaluated on the same
-                    # evidence. Only the write-only turnover branch is
-                    # certified across ring laps, so the lap decision and
-                    # allow_turnover below share this one value: a
-                    # quorum-but-not-all accept set must neither take the
-                    # lapped shape (the aliased fallback is uncertified
-                    # past one turnover) nor compile the turnover branch it
-                    # cannot reach.
-                    all_accept = bool(eligible and self._gate_accept.all())
-                    # Multi-lap fast path: the eligibility legs are
-                    # T-independent given take == T*B, and on an all-accept
-                    # cluster the write-only turnover kernel is valid
-                    # across ring laps (each step commits before its slots
-                    # are revisited), so a backlog covering
-                    # pipeline_max_laps ring turnovers rides ONE launch.
-                    # All-or-nothing on the lap count keeps the compile set
-                    # at exactly two programs.
-                    if (
-                        all_accept and cfg.pipeline_max_laps > 1
-                        and len(pending) >= cfg.pipeline_max_laps * T_ring * B
-                    ):
-                        T = cfg.pipeline_max_laps * T_ring
-                        take = T * B
                 if chunk_span is not None:
                     chunk_span.set_metadata(entries=take, padded=T * B)
                 with phase("raft.pack", chunk=chunk_id) as pack_span:
@@ -1110,87 +1064,6 @@ class RaftEngine:
                             bytes_per_chip=int(
                                 block * stack.dtype.itemsize + vectors),
                         )
-                if eligible:
-                    # The saturated fast path: the whole full-ring chunk as
-                    # ONE kernel launch (core.step_pallas.steady_pipeline_tpu
-                    # via the transport). The host gate below implies the
-                    # kernel's launch-feasibility predicate, so every step
-                    # ingests and commits a full batch — bookkeeping is the
-                    # contiguous mapping, verified by the commit assert.
-                    with phase("raft.dispatch", chunk=chunk_id):
-                        self.state, info = self.t.replicate_pipeline(
-                            self.state, payload_stack, jnp.asarray(counts),
-                            r, self.leader_term, jnp.asarray(eff),
-                            jnp.asarray(self.slow),
-                            # the pipeline kernel takes the bool VOTER
-                            # plane directly (no packed-mask decomposition
-                            # on this entry point — unlike
-                            # replicate/scan_replicate)
-                            member=(jnp.asarray(self.member)
-                                    if self.cfg.max_replicas is not None
-                                    else None),
-                            repair_floor=floor, floor_prev_term=fpt,
-                            term_floor=self._term_floor,
-                            # write-only turnover only when the host's
-                            # verified accept set covers EVERY row (same
-                            # value as the lap gate above — see its
-                            # comment); with False the program is the plain
-                            # pipeline-vs-scan two-way cond
-                            allow_turnover=all_accept,
-                        )
-                    with phase("raft.device_wait", chunk=chunk_id):
-                        self._note_truncations(pre_lasts)
-                        self._dev_record_chunk(
-                            dev_pre, info, r, self.leader_term, T
-                        )
-                        final_commit = int(info.commit_index)
-                    if final_commit != leader_last + take:
-                        # The host gate and the kernel's feasibility
-                        # predicate are meant to be equivalent; a desync
-                        # means mappings for the chunk cannot be trusted —
-                        # fail loudly rather than mis-account durable
-                        # entries. BUT first reconcile, so the exception is
-                        # survivable: account the committed prefix (it is
-                        # durable — its bytes must never be re-queued),
-                        # then truncate the orphaned uncommitted suffix off
-                        # the device log. Without the truncation the
-                        # re-queued payloads would coexist with an
-                        # unaccounted device copy, and a later repair tick
-                        # could replicate and commit both.
-                        done = min(max(final_commit - leader_last, 0), take)
-                        self._account_chunk_prefix(
-                            r, chunk, done, leader_last, eff, chunk_id
-                        )
-                        self._truncate_uncommitted_tail(
-                            leader_last + done,
-                            self._fetch(self.state.last_index),
-                        )
-                        # chunk[:done] is committed and stays accounted;
-                        # the rest of the chunk re-queues for a later tick
-                        self._queue = (
-                            list(chunk[done:]) + pending[take:] + deferred
-                            + self._queue
-                        )
-                        raise RuntimeError(
-                            f"pipeline chunk shortfall: committed "
-                            f"{final_commit}, expected {leader_last + take} "
-                            "(host feasibility gate out of sync with the "
-                            "kernel's launch predicate); device log "
-                            "reconciled, uncommitted remainder re-queued"
-                        )
-                    self._account_chunk_prefix(
-                        r, chunk, take, leader_last, eff, chunk_id
-                    )
-                    pending = pending[take:]
-                    with phase("raft.commit", chunk=chunk_id):
-                        self._confirm_reads(
-                            r, self.leader_term, eff, int(info.max_term)
-                        )
-                        self._update_steady(r, info.match, eff)
-                        if int(info.max_term) > self.leader_term:
-                            self._step_down_leader(r, int(info.max_term))
-                            break
-                    continue
                 with phase("raft.dispatch", chunk=chunk_id):
                     self.state, infos = self.t.replicate_many(
                         self.state, payload_stack, jnp.asarray(counts), r,
@@ -1257,111 +1130,6 @@ class RaftEngine:
         if self.leader_id == r:
             self._reset_heard_timers(r)
         return seqs
-
-    def _account_chunk_prefix(self, r: int, chunk, n: int,
-                              leader_last: int, eff, chunk_id: int) -> None:
-        """Durable accounting for the first ``n`` entries of a pipeline
-        chunk at contiguous indices after ``leader_last``: stamp seq and
-        payload bookkeeping, fence term durability to disk, then advance
-        the commit watermark (archive + ack). Shared by the fast path's
-        success and shortfall-reconcile branches so the two can never
-        drift on what "durably accounted" means."""
-        with _profiling.phase("raft.account", chunk=chunk_id):
-            for i, (seq, p) in enumerate(chunk[:n]):
-                idx = leader_last + 1 + i
-                self._seq_at_index[idx] = seq
-                self._uncommitted[idx] = (p, self.leader_term)
-            self.terms[eff] = np.maximum(self.terms[eff], self.leader_term)
-            self._persist_votes()
-        with _profiling.phase("raft.commit", chunk=chunk_id):
-            self._advance_commit(r, leader_last + n)
-
-    def _pipeline_eligible(self, r: int, take: int, T: int,
-                           leader_last: int, eff) -> bool:
-        """Host gate for the single-launch pipeline chunk: must IMPLY the
-        kernel's launch-feasibility predicate (core.step_pallas), so the
-        flight provably ingests and commits a full batch every step —
-        the contract the simplified contiguous bookkeeping rests on.
-
-        - the transport exposes the program and the shapes are
-          kernel-eligible (ring._pallas_ok);
-        - the chunk is exactly one full ring of full batches (counts all
-          B — padding heartbeat steps would break the affine geometry);
-        - the cluster is VERIFIED steady (every reachable non-slow
-          member's match at the leader's tail — the kernel's launch-time
-          accept set) and fully committed, with the start slot aligned;
-        - the accept set meets the commit quorum, and no reachable row
-          holds a higher term (those deny/depose instead of acking).
-
-        The accept set is verified against the CURRENT device state (one
-        fetch of the term/last/match vectors), not the ``_steady`` flag
-        alone: ``_update_steady`` is vacuously True when the previous
-        step's verified set was empty, and a flag can never prove the
-        rows counted toward quorum are at the leader's tail *now*. A row
-        counts only if its device log provably matches the leader's
-        through ``leader_last`` (same tail index, match verified in the
-        current term, no higher term) — a sufficient condition for the
-        kernel's per-row accept predicate, so host-feasible implies
-        kernel-feasible.
-        """
-        from raft_tpu.core.ring import _pallas_ok
-
-        cfg = self.cfg
-        B = cfg.batch_size
-        if not (
-            getattr(self.t, "replicate_pipeline", None) is not None
-            and _pipeline_backend_ok()
-            and take == T * B
-            and _pallas_ok(cfg.log_capacity, B)
-            and self._steady
-            and self.commit_watermark == leader_last
-        ):
-            return False
-        from raft_tpu.core.step_pallas import _pick_br
-
-        if leader_last % _pick_br(B, cfg.log_capacity) != 0:
-            return False
-        if np.any(self.terms[eff] > self.leader_term):
-            return False
-        lasts, matches, mterms, dterms = np.asarray(self._fetch(jnp.stack([
-            self.state.last_index, self.state.match_index,
-            self.state.match_term, self.state.term,
-        ])))
-        verified = (
-            (lasts == leader_last) & (dterms <= self.leader_term)
-            & (
-                (leader_last == 0)   # empty prefix: no prev point to
-                #                      verify (the kernel's ws0==1 clause)
-                | ((mterms == self.leader_term) & (matches >= leader_last))
-            )
-        )
-        # the leader's own row accepts its own frontier; it needs no
-        # verified match, only a current term and the expected tail
-        verified[r] = (
-            lasts[r] == leader_last and dterms[r] <= self.leader_term
-        )
-        accept = eff & ~self.slow & verified
-        # stashed for the caller: the multi-lap gate and allow_turnover
-        # must see the SAME per-row accept set this gate counted —
-        # all-rows-accept is the kernel's turnover predicate, and only
-        # the turnover branch is certified across ring laps
-        self._gate_accept = accept
-        if cfg.max_replicas is not None:
-            # mirror core.step_pallas._params_and_masks EXACTLY: member
-            # majority, clamped to the static commit_quorum only under EC
-            # (the k+margin durability floor); for non-EC the member
-            # majority alone governs, matching the general XLA path
-            quorum = int(self.member.sum()) // 2 + 1
-            if cfg.ec_enabled:
-                quorum = max(quorum, cfg.commit_quorum)
-        else:
-            quorum = cfg.commit_quorum
-        if cfg.max_replicas is not None:
-            # the kernel counts acks over VOTERS (alive & member on
-            # device); learner rows in the accept set replicate but must
-            # not be counted toward the host's quorum feasibility either
-            return int((accept & self.member).sum()) >= quorum
-        return int(accept.sum()) >= quorum
 
     @property
     def in_flight_count(self) -> int:
@@ -1777,9 +1545,7 @@ class RaftEngine:
         programs once per learner-attach/drain transition — a deliberate
         cost: the packed mask is the device-visible record of the full
         configuration, so the core/step learner support stays exercised
-        end to end rather than test-only. replicate_pipeline is the one
-        entry point that takes the bool voter plane directly (see the
-        submit_pipelined call site)."""
+        end to end rather than test-only."""
         if self.cfg.max_replicas is None:
             return None
         if self.learner.any():

@@ -276,40 +276,3 @@ class SingleDeviceTransport:
                 ring=ring, quorum=jnp.int32(quorum),
             )
         return self._vote(state, jnp.int32(candidate), jnp.int32(cand_term), alive)
-
-    def replicate_pipeline(
-        self, state, payloads, counts, leader, leader_term, alive, slow,
-        member=None, repair_floor=0, floor_prev_term=0, term_floor=1,
-        allow_turnover=True,
-    ) -> Tuple[ReplicaState, RepInfo]:
-        """T saturated steps as ONE kernel launch
-        (core.step_pallas.steady_pipeline_tpu) — the engine dispatches
-        this for full-batch chunks on a verified-steady cluster; the
-        launch-feasibility cond inside falls back to the per-step fused
-        scan. Returns the FINAL step's info only (the caller must verify
-        commit progress covers the whole chunk)."""
-        from functools import partial as _partial
-
-        from raft_tpu.core.ring import pallas_interpret
-        from raft_tpu.core.step_pallas import steady_pipeline_tpu
-
-        if not hasattr(self, "_pipeline_jit"):
-            self._pipeline_jit = labeled("single.pipeline", jax.jit(
-                _partial(
-                    steady_pipeline_tpu,
-                    commit_quorum=self.cfg.commit_quorum,
-                    ec=self.cfg.ec_enabled,
-                    interpret=pallas_interpret(),
-                ),
-                donate_argnums=(0,),
-                static_argnames=("allow_turnover",),
-            ))
-        if self._member_mode and member is None:
-            member = jnp.ones(self.cfg.rows, bool)
-        return self._pipeline_jit(
-            state, payloads, counts, jnp.int32(leader),
-            jnp.int32(leader_term), alive, slow,
-            jnp.int32(floor_prev_term), jnp.int32(repair_floor),
-            member if self._member_mode else None, jnp.int32(term_floor),
-            allow_turnover=bool(allow_turnover),
-        )

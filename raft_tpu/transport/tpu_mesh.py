@@ -259,11 +259,11 @@ class TpuMeshTransport:
     def _member_or_ones(self, member):
         return jnp.ones(self.cfg.rows, bool) if member is None else member
 
-    def _fused_program(self, kind: str, rep: bool, allow_turnover=True):
+    def _fused_program(self, kind: str, rep: bool):
         """shard_map programs that thread ``term_floor`` through, so the
         per-step dispatch inside core.step (one source of truth) can
         route to the per-device fused kernels. Built lazily per
-        (kind, repair[, turnover]) and process-cached."""
+        (kind, repair) and process-cached."""
         cfg = self.cfg
         comm = self._comm
         lanes = self._lanes
@@ -292,26 +292,9 @@ class TpuMeshTransport:
                     rf, member, term_floor=tf,
                 )
             win_spec = P(None, None, lanes)
-        else:                                    # "pipeline"
-            from raft_tpu.core.ring import pallas_interpret
-            from raft_tpu.core.step_mesh import mesh_pipeline
-
-            def fn(state, wins, counts, leader, lterm, alive, slow, fpt,
-                   rf, *rest):
-                member = rest[0] if mm else None
-                tf = rest[-1]
-                return mesh_pipeline(
-                    AXIS, state, wins, counts, leader, lterm, alive,
-                    slow, fpt, rf, member, tf,
-                    commit_quorum=cfg.commit_quorum, ec=cfg.ec_enabled,
-                    interpret=pallas_interpret(),
-                    allow_turnover=allow_turnover,
-                )
-            win_spec = P(None, None, lanes)
-
         return self._cached(
             f"tpu_mesh.{kind}",
-            ("fused_dispatch", kind, rep, allow_turnover),
+            ("fused_dispatch", kind, rep),
             lambda: jax.jit(
                 shard_map(
                     fn,
@@ -501,27 +484,6 @@ class TpuMeshTransport:
             jnp.int32(n_run), jnp.asarray(halted0, bool),
             jnp.int32(leader), jnp.int32(leader_term), alive, slow,
             jnp.int32(floor_prev_term), jnp.int32(repair_floor), *extra,
-        )
-
-    def replicate_pipeline(
-        self, state, payloads, counts, leader, leader_term, alive, slow,
-        member=None, repair_floor=0, floor_prev_term=0, term_floor=1,
-        allow_turnover=True,
-    ) -> Tuple[ReplicaState, RepInfo]:
-        """T saturated steps as ONE per-device kernel launch over the
-        mesh (core.step_mesh.mesh_pipeline): two launch collectives,
-        then a communication-free flight on every chip. Same contract
-        as the single-device ``replicate_pipeline`` — the engine's host
-        gate implies the (shared) launch-feasibility predicate and
-        verifies commit progress covers the chunk."""
-        extra = (self._member_or_ones(member),) if self._member_mode else ()
-        return self._fused_program(
-            "pipeline", True, bool(allow_turnover)
-        )(
-            state, payloads, counts, jnp.int32(leader),
-            jnp.int32(leader_term), alive, slow,
-            jnp.int32(floor_prev_term), jnp.int32(repair_floor),
-            *extra, jnp.int32(term_floor),
         )
 
     def request_votes(

@@ -1,11 +1,9 @@
 """``chip_smoke.py`` rehearsed on the CPU at a tiny scale.
 
 The script refuses to run without a TPU; these tests steer past that
-check from here (a stub ``device_check``), force the Pallas kernels into
-interpret mode, and open the engine's hardware-only pipeline gate, so the
-same phases and checks run end to end on virtual CPU devices. The lap
-gate's aliased revisit regime is beyond interpret mode and is stubbed;
-on the chip it runs for real.
+check from here (a stub ``device_check``) and force the Pallas kernels
+into interpret mode, so the same phases and checks run end to end on
+virtual CPU devices.
 """
 
 from __future__ import annotations
@@ -33,14 +31,9 @@ TINY = chip_smoke.Sizes(
 
 @pytest.fixture
 def on_cpu(monkeypatch):
-    import raft_tpu.core.gates as gates
-    import raft_tpu.raft.engine as engine
     from raft_tpu.core import ring
 
     monkeypatch.setattr(ring, "_force_interpret", True)
-    monkeypatch.setattr(engine, "_pipeline_backend_ok", lambda: True)
-    monkeypatch.setattr(gates, "pipeline_lap_gate",
-                        lambda rng, batch, interpret: {"lap_gate": "stub"})
     monkeypatch.setattr(chip_smoke, "device_check", lambda chips: {
         "platform": jax.devices()[0].platform, "kind": "cpu",
         "count": len(jax.devices()),
@@ -66,7 +59,7 @@ def test_one_chip_phases_end_to_end(on_cpu, capsys):
     b = phases["b_main_path"]
     assert b["committed"] == b["applied"] == TINY.stream
     assert b["sha256"] == b["applied_sha256"]
-    assert b["launches"]["single.pipeline"] >= 1
+    assert b["launches"]["single.replicate_many"] >= 1
     c = phases["c_failover"]
     assert c["term_after"] > c["term_before"]
     assert c["new_leader"] != c["old_leader"]

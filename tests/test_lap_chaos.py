@@ -195,17 +195,13 @@ def _ring_matches_archive(e):
     # tier-1 seed; the sibling rides the slow tier
     pytest.param(11, marks=pytest.mark.slow),
 ])
-def test_pipelined_multi_lap_under_chaos(seed, monkeypatch):
-    """The submit_pipelined fast path — including multi-lap turnover
-    flights (pipeline_max_laps=2) — interleaved with the fault
-    adversary, on the REAL kernels in interpret mode. The host gate must
-    refuse or launch consistently (a gate/kernel desync raises the
-    shortfall error and fails the test), and every retained committed
-    byte must match the archive at quiescence."""
-    import raft_tpu.raft.engine as engine_mod
+def test_pipelined_multi_lap_under_chaos(seed):
+    """submit_pipelined backlogs of two to four ring laps, each chunk a
+    lap-length scan, interleaved with the fault adversary, on the REAL
+    kernels in interpret mode: every retained committed byte must match
+    the archive at quiescence."""
     from raft_tpu.core import ring
 
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
     prior = ring._force_interpret
     ring.force_pallas_interpret(True)
     try:
@@ -213,33 +209,21 @@ def test_pipelined_multi_lap_under_chaos(seed, monkeypatch):
         cfg = RaftConfig(
             n_replicas=3, entry_bytes=16, batch_size=128,
             log_capacity=256, transport="single", seed=seed,
-            pipeline_max_laps=2,
         )
         e = RaftEngine(cfg, SingleDeviceTransport(cfg))
         e.run_until_leader()
-        T_lap = 2 * (cfg.log_capacity // cfg.batch_size)
-        lapped = [0]
-        orig = e.t.replicate_pipeline
-
-        def counting(state, payloads, counts, *a, **k):
-            if int(counts.shape[0]) == T_lap:
-                lapped[0] += 1
-            return orig(state, payloads, counts, *a, **k)
-
-        e.t.replicate_pipeline = counting
         partitioned = False
         for _ in range(6):
             if e.leader_id is None:
                 # the adversary can legally leave the cluster leaderless
-                # (leader killed in a partition): wait out an election
-                # rather than conflating 'requires a current leader'
-                # with the gate-desync failure this test exists to catch
+                # (leader killed in a partition): wait out an election,
+                # since submit_pipelined requires a current leader
                 e.run_for(60.0)
                 continue
             n = rng.randrange(2, 5) * 256
             ps = [bytes(rng.getrandbits(8) for _ in range(16))
                   for _ in range(n)]
-            e.submit_pipelined(ps)   # a shortfall RuntimeError fails here
+            e.submit_pipelined(ps)
             action = rng.choice(["kill", "recover", "partition", "heal",
                                  "campaign", "none"])
             victim = rng.randrange(3)
@@ -267,6 +251,5 @@ def test_pipelined_multi_lap_under_chaos(seed, monkeypatch):
         e.run_for(6 * cfg.heartbeat_period)
         assert e.commit_watermark > cfg.log_capacity
         assert _ring_matches_archive(e) > 0
-        assert lapped[0] > 0, "the lapped shape never launched"
     finally:
         ring.force_pallas_interpret(prior)

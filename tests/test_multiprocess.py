@@ -116,12 +116,13 @@ ks, kinfo = kt.replicate(ks, fold_batch(kb, R), 128, 0, 1, alive, slow,
 assert step_mesh.LAST_DISPATCH == "step", step_mesh.LAST_DISPATCH
 kcommit = int(kinfo.commit_index)
 assert kcommit == 128, f"fused mesh commit {kcommit}"
-wins = jnp.asarray(fold_batch(kb, R))[None]
+wins = jnp.stack([jnp.asarray(fold_batch(kb, R))] * 2)
 counts = jnp.full((2,), 128, jnp.int32)
-ks, kinfo = kt.replicate_pipeline(ks, wins, counts, 0, 1, alive, slow,
-                                  term_floor=1, allow_turnover=False)
-assert step_mesh.LAST_DISPATCH == "pipeline"
-assert int(kinfo.commit_index) == 3 * 128, int(kinfo.commit_index)
+ks, kinfos = kt.replicate_many(ks, wins, counts, 0, 1, alive, slow,
+                               repair=False, term_floor=1)
+assert step_mesh.LAST_DISPATCH == "scan", step_mesh.LAST_DISPATCH
+kcommit2 = int(np.asarray(kinfos.commit_index).ravel()[-1])
+assert kcommit2 == 3 * 128, kcommit2
 _ring.force_pallas_interpret(False)
 
 print(f"MPOK proc={jax.process_index()} commit={commit} "
@@ -272,25 +273,21 @@ from raft_tpu.transport.multihost import multihost_transport
 
 # The DEPLOYMENT SHAPE end to end: the full mirrored engine, replica
 # rows across OS processes, at a KERNEL-ELIGIBLE shape — every tick
-# rides the per-device fused mesh kernels (interpret mode), and the
-# pipelined fast path takes the per-device single-launch pipeline.
+# rides the per-device fused mesh kernels (interpret mode), and a
+# pipelined chunk rides the per-device fused scan.
 ring.force_pallas_interpret(True)
 cfg = RaftConfig(n_replicas=3, entry_bytes=16, batch_size=128,
                  log_capacity=256, transport="multihost", seed=7)
-import raft_tpu.raft.engine as engine_mod
-engine_mod._pipeline_backend_ok = lambda: True   # interpret CI override
 e = RaftEngine(cfg, multihost_transport(cfg))
 e.run_until_leader()
 step_mesh.LAST_DISPATCH = None
 rng = np.random.default_rng(42)
 ps = [rng.integers(0, 256, 16, np.uint8).tobytes() for _ in range(256)]
-seqs = [e.submit(p) for p in ps]          # 256: a BLOCK-ALIGNED tail,
-#                                           which the pipelined gate needs
+seqs = [e.submit(p) for p in ps]
 e.run_until_committed(seqs[-1], limit=900.0)
 assert step_mesh.LAST_DISPATCH is not None, "tick path not fused"
-# a full-ring pipelined chunk must ride the per-device single-launch
-# pipeline across the process boundary (the host gate verifies the
-# CURRENT device state collectively, then ONE launch per process)
+# a full-ring pipelined chunk must ride the per-device fused scan
+# across the process boundary (ONE launch per process)
 e.run_for(4 * cfg.heartbeat_period)
 dispatches = []
 ps_pipe = [rng.integers(0, 256, 16, np.uint8).tobytes()
@@ -298,7 +295,7 @@ ps_pipe = [rng.integers(0, 256, 16, np.uint8).tobytes()
 seqs_pipe = e.submit_pipelined(ps_pipe)
 dispatches.append(step_mesh.LAST_DISPATCH)
 e.run_until_committed(seqs_pipe[-1], limit=900.0)
-assert "pipeline" in dispatches, dispatches
+assert "scan" in dispatches, dispatches
 # leadership change + catch-up, all through the fused mesh kernels
 lead1 = e.leader_id
 e.fail(lead1)
@@ -314,7 +311,7 @@ want = (ps + ps_pipe + ps2)[lo - 1:]
 assert [bytes(x) for x in np.asarray(got)] == want
 h = hashlib.sha256(np.asarray(got).tobytes()).hexdigest()[:16]
 print(f"KENGOK proc={jax.process_index()} wm={e.commit_watermark} "
-      f"sha={h} pipeline={'pipeline' in dispatches}", flush=True)
+      f"sha={h} scan={'scan' in dispatches}", flush=True)
 '''
 
 
@@ -331,7 +328,7 @@ def test_two_process_full_engine_fused_kernels(tmp_path):
         assert mark, out[-500:]
         marks.append(mark[0].split(" ", 2)[2])   # drop "KENGOK proc=i"
     assert marks[0] == marks[1], f"processes diverged: {marks}"
-    assert "wm=568" in marks[0] and "pipeline=True" in marks[0]
+    assert "wm=568" in marks[0] and "scan=True" in marks[0]
 
 
 SURVIVOR_CHILD = r'''

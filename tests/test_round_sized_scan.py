@@ -14,14 +14,17 @@ Contracts under test, plain and RS(5,3):
    a one-batch round, the lap for a longer one.
 3. Rounds of every length below a lap compile two scan lengths per
    program variant, 1 and T_ring.
-4. The single-launch pipeline kernel takes whole laps only: a round of
-   exactly B entries runs the scan at T = 1, a whole lap one
-   ``replicate_pipeline`` launch at T = T_ring.
+4. Every chunk is one ``replicate_many`` launch: a round of exactly B
+   entries runs the scan at T = 1, a whole lap at T = T_ring.
 5. A follower that comes back several batches behind heals as under
    the lap: on the plain log, while it lags, a one-batch round keeps the
    lap, whose every step's repair window heals up to B of its entries;
    the RS step has no repair window, so the round runs one step and the
    tick path heals the follower.
+6. A backlog of 1, 2 or 3 whole laps in one call, plain and RS, on one
+   device and on the mesh, runs one ``replicate_many`` per lap at
+   T = T_ring and nothing else, commits and applies every entry once and
+   in order, and leaves every row's ring holding the stream.
 """
 
 import jax
@@ -34,6 +37,7 @@ from raft_tpu.ec.rs import RSCode
 from raft_tpu.obs.profiling import program_spans
 from raft_tpu.raft.engine import RaftEngine
 from raft_tpu.transport.device import SingleDeviceTransport
+from raft_tpu.transport.tpu_mesh import TpuMeshTransport
 
 B = 128
 CAP = 1 << 11
@@ -147,56 +151,92 @@ def test_every_round_length_compiles_two_scan_lengths(kind):
         assert p._cache_size() <= 2
 
 
-def pipeline_shim(e):
-    """Stand in for the pipeline kernel on the CPU (interpret mode cannot
-    model a whole-ring flight's in-place revisits): the same steps as the
-    repair-free scan, returning the final step's info. Returns the scan
-    length of every launch."""
+@KINDS
+@pytest.mark.parametrize("n,scans", [
+    (B, [1]),                          # one batch: the sized scan
+    (T_RING * B, [T_RING]),            # a whole lap: the lap-length scan
+], ids=["one_batch", "whole_lap"])
+def test_pipeline_kernel_takes_whole_laps_only(monkeypatch, kind, n, scans):
+    """A pipelined chunk of one batch and one of a whole lap each run as
+    one ``replicate_many`` launch, on the fused kernels (interpret mode
+    here), at T = 1 and T = T_RING."""
+    from raft_tpu.core import ring
+
+    monkeypatch.setattr(ring, "_force_interpret", True)
+    e = mk_engine(**kind)
+    got_scans = count_calls(e, "replicate_many")
+    body = as_bytes(payloads(e.cfg, n, 4))
+    seqs = e.submit_pipelined(body)
+    assert all(e.is_durable(s) for s in seqs)
+    assert got_scans == scans
+    assert [bytes(x) for x in np.asarray(e.committed_entries(1, n))] == body
+
+
+def record_launches(e):
+    """Record every replication launch on ``e``'s transport as (entry
+    point, scan length or None)."""
     launches = []
-    t = e.t
+    for name in ("replicate", "replicate_fused", "replicate_many"):
+        orig = getattr(e.t, name)
 
-    def shim(state, payloads, counts, r, term, alive, slow, member=None,
-             repair_floor=0, floor_prev_term=0, term_floor=1,
-             allow_turnover=True):
-        launches.append(int(counts.shape[0]))
-        st, infos = t.replicate_many(
-            state, payloads, counts, r, term, alive, slow, repair=False,
-            member=member, repair_floor=repair_floor,
-            floor_prev_term=floor_prev_term, term_floor=term_floor,
-        )
-        return st, jax.tree.map(lambda a: a[-1], infos)
+        def counting(*a, _name=name, _orig=orig, **k):
+            counts = a[2] if _name == "replicate_many" else None
+            launches.append((_name, None if counts is None
+                             else int(counts.shape[0])))
+            return _orig(*a, **k)
 
-    t.replicate_pipeline = shim
+        setattr(e.t, name, counting)
     return launches
 
 
-@KINDS
-@pytest.mark.parametrize("n,launches,scans", [
-    (B, [], [1]),                      # one batch: the sized scan
-    (T_RING * B, [T_RING], []),        # a whole lap: one kernel launch
-], ids=["one_batch", "whole_lap"])
-def test_pipeline_kernel_takes_whole_laps_only(monkeypatch, kind, n,
-                                               launches, scans):
-    import raft_tpu.raft.engine as engine_mod
+@pytest.mark.parametrize("laps", [1, 2, 3])
+@pytest.mark.parametrize("kind,transport", [
+    (PLAIN, "single"), (RS, "single"), (PLAIN, "tpu_mesh"), (RS, "tpu_mesh"),
+], ids=["plain-single", "rs-single", "plain-mesh", "rs-mesh"])
+def test_whole_lap_backlog_runs_the_lap_scan(monkeypatch, kind, transport,
+                                              laps):
+    """A backlog of whole laps submitted in one call on a steady cluster,
+    the shape a single-launch lap kernel once took: one ``replicate_many``
+    per lap at T = T_RING, on the fused kernels (interpret mode here),
+    every entry committed and applied once in order, every row's ring
+    holding the stream (under RS, its shard)."""
+    import raft_tpu.core.step_mesh as step_mesh
     from raft_tpu.core import ring
+    from raft_tpu.transport import tpu_mesh
 
-    # the gate's backend legs, as on the chip
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
     monkeypatch.setattr(ring, "_force_interpret", True)
-    cfg = RaftConfig(batch_size=B, log_capacity=CAP, transport="single",
+    # LAST_DISPATCH is a trace-time witness: clear the process-wide mesh
+    # programs so this test traces the scan it asserts about
+    tpu_mesh._PROGRAMS.clear()
+    step_mesh.LAST_DISPATCH = None
+    cfg = RaftConfig(batch_size=B, log_capacity=CAP, transport=transport,
                      seed=5, **kind)
-    e = RaftEngine(cfg, SingleDeviceTransport(cfg))
+    t = (TpuMeshTransport(cfg, jax.devices()[:cfg.rows])
+         if transport == "tpu_mesh" else SingleDeviceTransport(cfg))
+    e = RaftEngine(cfg, t)
     e.run_until_leader()
-    e._steady = True                 # fresh cluster, all rows at 0
-    got_launches = pipeline_shim(e)
-    got_scans = count_calls(e, "replicate_many")
-    body = as_bytes(payloads(cfg, n, 4))
-    seqs = e.submit_pipelined(body)
+    e.run_for(cfg.heartbeat_period)
+    applied = []
+    e.register_apply(lambda i, p: applied.append((i, bytes(p))))
+    launches = record_launches(e)
+    n = laps * T_RING * B
+    stream = np.stack(payloads(cfg, n, 30 + laps))
+    seqs = e.submit_pipelined(as_bytes(stream))
+
+    assert launches == [("replicate_many", T_RING)] * laps
+    if transport == "tpu_mesh":
+        assert step_mesh.LAST_DISPATCH == "scan"
     assert all(e.is_durable(s) for s in seqs)
-    assert got_launches == launches
-    # the shim's own scan shows here too, at the lap's length
-    assert got_scans == (scans or launches)
-    assert [bytes(x) for x in np.asarray(e.committed_entries(1, n))] == body
+    assert e.commit_watermark == n
+    assert applied == list(zip(range(1, n + 1), as_bytes(stream)))
+    want = stream[n - CAP:]
+    slots = np.arange(n - CAP, n) % CAP
+    shards = RSCode(cfg.rows, cfg.rs_k).encode(want) if cfg.ec_enabled \
+        else None
+    for r in range(cfg.rows):
+        got = payload_slot_bytes(e.state, r, fetch=e._fetch)[slots]
+        exp = shards[r] if shards is not None else want
+        assert np.array_equal(got[:, :exp.shape[1]], exp), f"row {r}"
 
 
 @pytest.mark.parametrize("cell", ["etcd3.put1000", "rs53.put1000"])

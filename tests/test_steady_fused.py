@@ -405,585 +405,3 @@ def test_ec_inline_parity_encode_matches_general():
             getattr(st_f, f), err_msg=f"state.{f}",
         )
     assert int(infos_g[-1].commit_index) == 3 * B + 100
-
-
-class TestPipelineKernel:
-    """steady_pipeline_tpu: T saturated steps as ONE pallas_call."""
-
-    def _run_both(self, cfg, wins, counts, slow, ec_consts=None,
-                  mk_payload=None):
-        from raft_tpu.core.step_pallas import (
-            steady_pipeline_tpu, steady_scan_replicate_tpu,
-        )
-
-        n = cfg.n_replicas
-        alive = jnp.ones(n, bool)
-        slow = jnp.asarray(slow)
-        T = counts.shape[0]
-        # reference: the per-step fused scan fed the same windows
-        xs = jnp.stack([wins[t % wins.shape[0]] for t in range(T)])
-        st_s, info_s = steady_scan_replicate_tpu(
-            init_state(cfg), xs, counts, jnp.int32(0), jnp.int32(1),
-            alive, slow, jnp.int32(0), jnp.int32(0), None, jnp.int32(1),
-            commit_quorum=cfg.commit_quorum, stack_infos=False,
-            interpret=ring.pallas_interpret(), ec_consts=ec_consts,
-        )
-        st_p, info_p = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, jnp.int32(0), jnp.int32(1),
-            alive, slow, jnp.int32(0), jnp.int32(0), None, jnp.int32(1),
-            commit_quorum=cfg.commit_quorum,
-            interpret=ring.pallas_interpret(), ec_consts=ec_consts,
-        )
-        st_s = jax.tree.map(np.asarray, st_s)
-        st_p = jax.tree.map(np.asarray, st_p)
-        for f in ("term", "voted_for", "last_index", "commit_index",
-                  "match_index", "match_term", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                getattr(st_s, f), getattr(st_p, f), err_msg=f"state.{f}"
-            )
-        for f in ("commit_index", "match", "max_term"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(info_s, f)),
-                np.asarray(getattr(info_p, f)), err_msg=f"info.{f}"
-            )
-        return st_p, info_p
-
-    def test_saturated_matches_scan(self):
-        # interpret-mode faithful range: no block revisited in one
-        # flight (T*B <= C); the revisit/lap regime is byte-asserted on
-        # real hardware by bench.py's pipeline probe
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        T = 7
-        wins = jnp.stack([batch(900 + t, B) for t in range(4)])   # P=4
-        counts = jnp.full((T,), B, jnp.int32)
-        st, info = self._run_both(cfg, wins, counts, [False] * N)
-        assert int(info.commit_index) == T * B
-
-    @pytest.mark.slow   # wall budget (README "Testing strategy"): composition
-    #   variant; its base equivalence pin stays tier-1
-    def test_slow_follower_matches_scan(self):
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        wins = batch(77, B)[None]
-        counts = jnp.full((5,), B, jnp.int32)
-        st, info = self._run_both(
-            cfg, wins, counts, [False, False, True]
-        )
-        assert int(info.commit_index) == 5 * B
-
-    def test_backpressure_degrades_to_prefix(self):
-        """Quorum stalled (two slow): the launch-feasibility predicate
-        fails (accept set below quorum) and the cond routes the call to
-        the per-step scan — a committed/appended PREFIX, never
-        corruption, byte-identical to the scan by construction."""
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=C)
-        wins = batch(78, B)[None]
-        counts = jnp.full((4,), B, jnp.int32)
-        st, info = self._run_both(
-            cfg, wins, counts, [False, True, True]
-        )
-        assert int(np.asarray(st.last_index)[0]) == C   # 2 steps appended
-        assert int(info.commit_index) == 0
-
-    @pytest.mark.slow   # wall budget (README "Testing strategy"): composition
-    #   variant; its base equivalence pin stays tier-1
-    def test_member_shrunk_pipeline_commits(self):
-        """ADVICE r4 (medium), pipeline flavor: with membership shrunk
-        below the initial majority (non-EC), the launch-feasibility
-        quorum is the member majority — the flight stays feasible and
-        commits, identically on pipeline and scan."""
-        from raft_tpu.core.step_pallas import (
-            steady_pipeline_tpu, steady_scan_replicate_tpu,
-        )
-
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        T = 5
-        wins = jnp.stack([batch(950 + t, B) for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        member = jnp.asarray([True, False, False])
-        args = (jnp.int32(0), jnp.int32(1), jnp.ones(N, bool),
-                jnp.zeros(N, bool), jnp.int32(0), jnp.int32(0), member,
-                jnp.int32(1))
-        st_s, _ = steady_scan_replicate_tpu(
-            init_state(cfg), wins, counts, *args,
-            commit_quorum=cfg.commit_quorum, stack_infos=False,
-            interpret=True,
-        )
-        st_p, info = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, *args,
-            commit_quorum=cfg.commit_quorum, interpret=True,
-        )
-        assert int(info.commit_index) == T * B
-        for f in ("last_index", "commit_index", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-                err_msg=f"state.{f}",
-            )
-
-    @pytest.mark.slow   # EC/multi-lap COMPOSITION variant: the non-EC / single-lap
-    #   equivalence pins stay tier-1; this rides the slow lane for wall budget
-    def test_ec_pipeline_matches_scan(self):
-        from raft_tpu.ec.kernels import fold_data_lanes, parity_consts
-
-        n, k = 5, 3
-        cfg = RaftConfig(n_replicas=n, entry_bytes=24, batch_size=B,
-                         log_capacity=1024, rs_k=k, rs_m=n - k)
-        rng = np.random.default_rng(11)
-        T = 5
-        raw = rng.integers(0, 256, (T, B, 24), dtype=np.uint8)
-        wins = jnp.stack([fold_data_lanes(jnp.asarray(raw[t]))
-                          for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        st, info = self._run_both(
-            cfg, wins, counts, [False] * n,
-            ec_consts=parity_consts(n, k),
-        )
-        assert int(info.commit_index) == T * B
-
-
-@pytest.mark.slow   # EC/multi-lap COMPOSITION variant: the non-EC / single-lap
-#   equivalence pins stay tier-1; this rides the slow lane for wall budget
-def test_engine_pipeline_chunk_gate_and_bookkeeping(monkeypatch):
-    """The engine's submit_pipelined fast path: full-ring chunks on a
-    verified-steady cluster go through transport.replicate_pipeline as
-    one launch, with contiguous seq bookkeeping — byte-identical to an
-    engine that never takes the fast path. CI exercises the gate and the
-    bookkeeping through a transport shim (the real kernel's lap regime
-    is hardware-gated in bench.py)."""
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    rng = np.random.default_rng(21)
-    ps = [rng.integers(0, 256, 8, dtype=np.uint8).tobytes()
-          for _ in range(640 + 120)]
-
-    def build(shimmed):
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=C, seed=6)
-        t = SingleDeviceTransport(cfg)
-        calls = []
-        if shimmed:
-            def shim(state, payloads, counts, r, term, alive, slow,
-                     member=None, repair_floor=0, floor_prev_term=0,
-                     term_floor=1, allow_turnover=True):
-                calls.append(int(counts.shape[0]))
-                st, infos = t.replicate_many(
-                    state, payloads, counts, r, term, alive, slow,
-                    repair=False, member=member, repair_floor=repair_floor,
-                    floor_prev_term=floor_prev_term, term_floor=term_floor,
-                )
-                return st, jax.tree.map(lambda a: a[-1], infos)
-
-            t.replicate_pipeline = shim
-            import raft_tpu.raft.engine as engine_mod
-            monkeypatch.setattr(
-                engine_mod, "_pipeline_backend_ok", lambda: True
-            )
-        else:
-            # the fast path must not trigger: no transport support
-            t.replicate_pipeline = None
-            monkeypatch.setattr(
-                RaftEngine, "_pipeline_eligible",
-                lambda self, *a, **k: False,
-            )
-        e = RaftEngine(cfg, t)
-        e.run_until_leader()
-        # warm to verified-steady at a BLOCK-ALIGNED tail (the fast path
-        # requires last % BR == 0: misaligned starts would make the
-        # flight's spill blocks content-bearing distance-1 revisits)
-        warm = [e.submit(p) for p in ps[:128]]
-        e.run_until_committed(warm[-1])
-        e.run_for(4 * cfg.heartbeat_period)
-        seqs = e.submit_pipelined(ps[128:])       # 632 = 2 full chunks + 120
-        e.run_until_committed(seqs[-1], limit=900.0)
-        got = [bytes(x) for x in
-               np.asarray(e.committed_entries(
-                   max(1, e.commit_watermark - C + 1), e.commit_watermark))]
-        return e, calls, got
-
-    e1, calls, got1 = build(shimmed=True)
-    assert calls, "full-ring chunks never took the pipeline fast path"
-    e2, _, got2 = build(shimmed=False)
-    assert got1 == got2, "fast-path committed bytes diverged"
-    assert e1.commit_watermark == e2.commit_watermark
-
-
-def test_engine_pipeline_gate_negative_cases(monkeypatch):
-    """Each leg of the host gate refuses on its own: partial chunks,
-    misaligned tails, unsteady clusters, uncommitted backlogs, quorum
-    shortfalls, and non-TPU backends."""
-    import raft_tpu.raft.engine as engine_mod
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                     log_capacity=C, seed=7)
-    t = SingleDeviceTransport(cfg)
-    e = RaftEngine(cfg, t)
-    e.run_until_leader()
-    r = e.leader_id
-    T = C // B
-    eff = e._reach(r)
-    e._steady = True
-    # backend gate: everything else fine, but not on TPU -> refuse
-    assert not e._pipeline_eligible(r, T * B, T, 0, eff)
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
-    assert e._pipeline_eligible(r, T * B, T, 0, eff)
-    # partial chunk
-    assert not e._pipeline_eligible(r, T * B - 4, T, 0, eff)
-    # misaligned tail
-    assert not e._pipeline_eligible(r, T * B, T, 8, eff)
-    # unsteady cluster
-    e._steady = False
-    assert not e._pipeline_eligible(r, T * B, T, 0, eff)
-    e._steady = True
-    # uncommitted backlog (watermark behind the tail)
-    e.commit_watermark = 0
-    assert not e._pipeline_eligible(r, T * B, T, B, eff)
-    # quorum shortfall: one live non-slow member is not a majority of 3
-    only_leader = np.zeros(cfg.rows, bool)
-    only_leader[r] = True
-    assert not e._pipeline_eligible(r, T * B, T, 0, only_leader)
-    # higher term visible on a reachable row
-    e.terms[(r + 1) % N] = e.leader_term + 1
-    assert not e._pipeline_eligible(r, T * B, T, 0, eff)
-
-
-@pytest.mark.slow   # EC/multi-lap COMPOSITION variant: the non-EC / single-lap
-#   equivalence pins stay tier-1; this rides the slow lane for wall budget
-def test_engine_multi_lap_chunk(monkeypatch):
-    """cfg.pipeline_max_laps > 1: a backlog covering several ring
-    turnovers rides ONE replicate_pipeline launch (the write-only
-    turnover kernel is lap-legal and interpret-faithful, so CI drives
-    the REAL kernel here) — byte-identical to the single-lap engine."""
-    import raft_tpu.raft.engine as engine_mod
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
-    rng = np.random.default_rng(51)
-    ps = [rng.integers(0, 256, 8, dtype=np.uint8).tobytes()
-          for _ in range(3 * C)]          # 3 ring turnovers of backlog
-
-    def run(max_laps):
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=C, seed=13,
-                         pipeline_max_laps=max_laps)
-        t = SingleDeviceTransport(cfg)
-        calls = []
-        orig = t.replicate_pipeline
-
-        def counting(state, payloads, counts, *a, **k):
-            calls.append(int(counts.shape[0]))
-            return orig(state, payloads, counts, *a, **k)
-
-        t.replicate_pipeline = counting
-        e = RaftEngine(cfg, t)
-        e.run_until_leader()
-        e._steady = True                 # fresh cluster, all rows at 0
-        seqs = e.submit_pipelined(ps)
-        e.run_until_committed(seqs[-1], limit=900.0)
-        got = [bytes(x) for x in np.asarray(
-            e.committed_entries(e.commit_watermark - C + 1,
-                                e.commit_watermark))]
-        return e, calls, got
-
-    e1, calls1, got1 = run(max_laps=2)
-    e2, calls2, got2 = run(max_laps=1)
-    T_ring = C // B
-    assert 2 * T_ring in calls1, f"no lapped launch happened: {calls1}"
-    assert all(c == T_ring for c in calls2)
-    assert len(calls1) < len(calls2), "laps did not reduce launch count"
-    assert e1.commit_watermark == e2.commit_watermark == 3 * C
-    assert got1 == got2 == ps[-C:]
-
-
-def test_multi_lap_requires_all_rows_verified(monkeypatch):
-    """A quorum-but-not-ALL accept set must refuse the lapped shape:
-    only the write-only turnover branch is certified across ring laps,
-    and the kernel would silently fall back to the aliased pipeline for
-    a row outside the accept set. The single-ring launch (which that
-    fallback IS certified for) must still run."""
-    import raft_tpu.raft.engine as engine_mod
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
-    cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                     log_capacity=C, seed=14, pipeline_max_laps=2)
-    t = SingleDeviceTransport(cfg)
-    calls = []
-    orig = t.replicate_pipeline
-
-    def counting(state, payloads, counts, *a, **k):
-        calls.append((int(counts.shape[0]), k.get("allow_turnover")))
-        return orig(state, payloads, counts, *a, **k)
-
-    t.replicate_pipeline = counting
-    e = RaftEngine(cfg, t)
-    e.run_until_leader()
-    e._steady = True
-    # degrade ONE follower's verified match on the quiet: quorum still
-    # holds (leader + other follower at tail 0) but all-accept does not
-    victim = (e.leader_id + 1) % N
-    e.state = e.state.replace(
-        match_index=e.state.match_index.at[victim].set(0),
-        match_term=e.state.match_term.at[victim].set(-1),
-        last_index=e.state.last_index.at[victim].set(0),
-    )
-    # force a non-empty prefix so verified needs a real match (the
-    # leader_last==0 clause would trivially verify everyone)
-    rng = np.random.default_rng(60)
-    warm = [e.submit(rng.integers(0, 256, 8, np.uint8).tobytes())
-            for _ in range(B)]
-    e.run_until_committed(warm[-1])
-    e.run_for(4 * cfg.heartbeat_period)
-    e.set_slow(victim, True)    # keep it from re-verifying...
-    e.set_slow(victim, False)   # ...but leave it in the accept masks
-    e.state = e.state.replace(
-        match_index=e.state.match_index.at[victim].set(0),
-        match_term=e.state.match_term.at[victim].set(-1),
-    )
-    e._steady = True
-    calls.clear()
-    ps = [rng.integers(0, 256, 8, np.uint8).tobytes()
-          for _ in range(2 * C)]
-    seqs = e.submit_pipelined(ps)
-    e.run_until_committed(seqs[-1], limit=900.0)
-    assert calls, "pipeline fast path never ran"
-    T_ring = C // B
-    first_T, first_turnover = calls[0]
-    assert first_T == T_ring, f"lapped shape launched: {calls[0]}"
-    assert first_turnover is False
-
-
-def test_pipeline_gate_verifies_current_accept_set(monkeypatch):
-    """ADVICE r4 (low): the gate must not trust the (possibly vacuously
-    true) ``_steady`` flag — rows counted toward the launch quorum are
-    verified against the CURRENT device last/match/term vectors, so a
-    row that lags NOW is never counted no matter what the flag says."""
-    import raft_tpu.raft.engine as engine_mod
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                     log_capacity=C, seed=8)
-    t = SingleDeviceTransport(cfg)
-    e = RaftEngine(cfg, t)
-    e.run_until_leader()
-    r = e.leader_id
-    T = C // B
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
-    ps = [bytes([i % 256]) * 8 for i in range(B)]
-    seqs = [e.submit(p) for p in ps]
-    e.run_until_committed(seqs[-1])
-    e.run_for(4 * cfg.heartbeat_period)
-    eff = e._reach(r)
-    e._steady = True
-    leader_last = int(np.asarray(e.state.last_index)[r])
-    assert e.commit_watermark == leader_last
-    assert e._pipeline_eligible(r, T * B, T, leader_last, eff)
-    # degrade both followers' device match on the quiet; the flag alone
-    # would still admit the flight — the state verification must refuse
-    e.state = e.state.replace(
-        match_index=jnp.zeros_like(e.state.match_index)
-    )
-    e._steady = True
-    assert not e._pipeline_eligible(r, T * B, T, leader_last, eff)
-
-
-def test_pipeline_shortfall_reconciles_device_log(monkeypatch):
-    """ADVICE r4 (low), second half: if the kernel still falls short of
-    the host gate's expectation, the engine must reconcile — truncate
-    the orphaned uncommitted suffix off the device log BEFORE re-queuing
-    the bytes — so a later tick can never commit two copies. The
-    exception stays (gate/kernel desync is a bug signal) but is
-    survivable: the same engine then commits every payload exactly
-    once through the regular tick path."""
-    import raft_tpu.raft.engine as engine_mod
-    from raft_tpu.raft import RaftEngine
-    from raft_tpu.transport import SingleDeviceTransport
-
-    cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                     log_capacity=C, seed=9)
-    t = SingleDeviceTransport(cfg)
-
-    def sabotaged(state, payloads, counts, rr, term, alive, slow,
-                  member=None, repair_floor=0, floor_prev_term=0,
-                  term_floor=1, allow_turnover=True):
-        # every follower silently drops the chunk: the leader ingests it
-        # all, nothing commits — the worst-case gate/kernel desync
-        allslow = jnp.ones_like(jnp.asarray(slow), bool)
-        st, infos = t.replicate_many(
-            state, payloads, counts, rr, term, alive, allslow,
-            repair=False, member=member, repair_floor=repair_floor,
-            floor_prev_term=floor_prev_term, term_floor=term_floor,
-        )
-        return st, jax.tree.map(lambda a: a[-1], infos)
-
-    t.replicate_pipeline = sabotaged
-    monkeypatch.setattr(engine_mod, "_pipeline_backend_ok", lambda: True)
-    e = RaftEngine(cfg, t)
-    e.run_until_leader()
-    r = e.leader_id
-    rng = np.random.default_rng(33)
-    ps = [rng.integers(0, 256, 8, dtype=np.uint8).tobytes()
-          for _ in range(C)]
-    e._steady = True   # flag says steady; the device state agrees (all
-    #                    at tail 0) — only the sabotaged kernel desyncs
-    with pytest.raises(RuntimeError, match="pipeline chunk shortfall"):
-        e.submit_pipelined(ps)
-    # device log reconciled: the orphaned suffix is gone everywhere
-    assert int(np.asarray(e.state.last_index).max()) == 0
-    assert len(e._queue) == len(ps)
-    # the re-queued bytes commit exactly once through the regular path
-    t.replicate_pipeline = None
-    for _ in range(200):
-        if e.commit_watermark >= len(ps):
-            break
-        e.run_for(cfg.heartbeat_period)
-    assert e.commit_watermark == len(ps)
-    got = [bytes(x) for x in
-           np.asarray(e.committed_entries(1, len(ps)))]
-    assert got == ps
-
-
-class TestTurnoverKernel:
-    """The write-only full-turnover pipeline: no ring inputs, no
-    aliasing — interpret mode is faithful here even across ring laps,
-    so CI pins the lap regime directly."""
-
-    def test_full_turnover_matches_scan_across_laps(self):
-        from raft_tpu.core.step_pallas import (
-            steady_pipeline_tpu, steady_scan_replicate_tpu,
-        )
-
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=C)
-        T = 7                                   # 896 over 256: 3.5 laps
-        wins = jnp.stack([batch(700 + t, B) for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        args = (jnp.int32(0), jnp.int32(1), jnp.ones(N, bool),
-                jnp.zeros(N, bool), jnp.int32(0), jnp.int32(0), None,
-                jnp.int32(1))
-        st_s, _ = steady_scan_replicate_tpu(
-            init_state(cfg), wins, counts, *args, commit_quorum=None,
-            stack_infos=False, interpret=True,
-        )
-        st_p, info = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, *args, commit_quorum=None,
-            interpret=True,
-        )
-        assert int(info.commit_index) == T * B
-        for f in ("term", "voted_for", "last_index", "commit_index",
-                  "match_index", "match_term", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-                err_msg=f"state.{f}",
-            )
-
-    def test_slow_row_keeps_general_path(self):
-        """A non-accepting row must keep the flight off the write-only
-        kernel (its lanes would be garbage). Below turnover scale
-        (T*B < C) the two-way dispatch serves; the turnover-scale
-        routing itself is asserted in test_slow_row_turnover_scale and
-        on hardware by bench.py's lap gate."""
-        from raft_tpu.core.step_pallas import (
-            steady_pipeline_tpu, steady_scan_replicate_tpu,
-        )
-
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)   # no revisit: interpret-safe
-        T = 7
-        wins = jnp.stack([batch(800 + t, B) for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        slow1 = jnp.zeros(N, bool).at[2].set(True)
-        args = (jnp.int32(0), jnp.int32(1), jnp.ones(N, bool), slow1,
-                jnp.int32(0), jnp.int32(0), None, jnp.int32(1))
-        st_s, _ = steady_scan_replicate_tpu(
-            init_state(cfg), wins, counts, *args, commit_quorum=None,
-            stack_infos=False, interpret=True,
-        )
-        st_p, info = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, *args, commit_quorum=None,
-            interpret=True,
-        )
-        assert int(info.commit_index) == T * B
-        for f in ("last_index", "commit_index", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-                err_msg=f"state.{f}",
-            )
-        # row 2's ring must be PRESERVED zeros (slow: nothing appended)
-        assert int(np.asarray(st_p.last_index)[2]) == 0
-
-    @pytest.mark.slow   # EC/multi-lap COMPOSITION variant: the non-EC / single-lap
-    #   equivalence pins stay tier-1; this rides the slow lane for wall budget
-    def test_ec_turnover_matches_scan(self):
-        from raft_tpu.core.step_pallas import (
-            steady_pipeline_tpu, steady_scan_replicate_tpu,
-        )
-        from raft_tpu.ec.kernels import fold_data_lanes, parity_consts
-
-        n, k = 5, 3
-        cfg = RaftConfig(n_replicas=n, entry_bytes=24, batch_size=B,
-                         log_capacity=C, rs_k=k, rs_m=n - k)
-        T = 5
-        rng = np.random.default_rng(13)
-        raw = rng.integers(0, 256, (T, B, 24), dtype=np.uint8)
-        wins = jnp.stack([fold_data_lanes(jnp.asarray(raw[t]))
-                          for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        args = (jnp.int32(0), jnp.int32(1), jnp.ones(n, bool),
-                jnp.zeros(n, bool), jnp.int32(0), jnp.int32(0), None,
-                jnp.int32(1))
-        consts = parity_consts(n, k)
-        st_s, _ = steady_scan_replicate_tpu(
-            init_state(cfg), wins, counts, *args,
-            commit_quorum=cfg.commit_quorum, stack_infos=False,
-            interpret=True, ec_consts=consts,
-        )
-        st_p, info = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, *args,
-            commit_quorum=cfg.commit_quorum, interpret=True,
-            ec_consts=consts,
-        )
-        assert int(info.commit_index) == T * B
-        for f in ("last_index", "commit_index", "log_term", "log_payload"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(st_s, f)), np.asarray(getattr(st_p, f)),
-                err_msg=f"state.{f}",
-            )
-
-
-    @pytest.mark.slow   # wall budget (README "Testing strategy"): composition
-    #   variant; its base equivalence pin stays tier-1
-    def test_slow_row_turnover_scale_preserves_quiet_rows(self):
-        """At turnover scale with a non-accepting row, all_accept must
-        route to the general (aliased) pipeline: the quiet row's ring
-        stays byte-identical to its pre-flight content. (Interpret mode
-        cannot model the accepting rows' revisited lanes here — those
-        are hardware-gated in bench.py — but the PRESERVED lanes read
-        the pre-call buffer either way, so this assertion is sound.)"""
-        from raft_tpu.core.step_pallas import steady_pipeline_tpu
-
-        cfg = RaftConfig(n_replicas=N, entry_bytes=8, batch_size=B,
-                         log_capacity=C)
-        T = 4                                    # T*B = 2*C: turnover scale
-        wins = jnp.stack([batch(850 + t, B) for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        slow1 = jnp.zeros(N, bool).at[2].set(True)
-        st, info = steady_pipeline_tpu(
-            init_state(cfg), wins, counts, jnp.int32(0), jnp.int32(1),
-            jnp.ones(N, bool), slow1, jnp.int32(0), jnp.int32(0), None,
-            jnp.int32(1), commit_quorum=None, interpret=True,
-        )
-        assert int(info.commit_index) == T * B
-        assert int(np.asarray(st.last_index)[2]) == 0
-        # the quiet row's payload lanes: untouched init zeros
-        W = cfg.shard_words
-        lanes = np.asarray(st.log_payload)[:, 2 * W:3 * W]
-        assert (lanes == 0).all(), "slow row's ring lanes were clobbered"

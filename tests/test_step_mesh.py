@@ -112,84 +112,6 @@ def test_mesh_fused_scan_matches_single():
     assert int(outs["mesh"][1].commit_index) == T * B
 
 
-class TestMeshPipeline:
-    def _run_both(self, cfg, slow, T, allow_turnover=True, seed0=200,
-                  member=None):
-        n = cfg.n_replicas
-        mesh_t = TpuMeshTransport(cfg, jax.devices()[:n])
-        single_t = SingleDeviceTransport(cfg)
-        alive = jnp.ones(cfg.rows, bool)
-        slow = jnp.asarray(slow)
-        wins = jnp.stack([batch(seed0 + t, B, cfg.rows) for t in range(T)])
-        counts = jnp.full((T,), B, jnp.int32)
-        outs = {}
-        step_mesh.LAST_DISPATCH = None
-        for name, t in (("mesh", mesh_t), ("single", single_t)):
-            s = t.init()
-            s, _ = t.request_votes(s, 0, 1, alive)
-            s, info = t.replicate_pipeline(
-                s, wins, counts, 0, 1, alive, slow, member=member,
-                term_floor=1, allow_turnover=allow_turnover,
-            )
-            outs[name] = (s, info)
-        assert step_mesh.LAST_DISPATCH == "pipeline"
-        return outs
-
-    def test_saturated_pipeline_matches_single(self):
-        # no block revisited in one flight: interpret-faithful for the
-        # aliased pipeline branch
-        cfg = RaftConfig(n_replicas=3, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        outs = self._run_both(cfg, [False] * 3, T=7, allow_turnover=False)
-        assert_same(outs["mesh"], outs["single"], 3, 7 * B)
-        assert int(outs["mesh"][1].commit_index) == 7 * B
-
-    @pytest.mark.slow
-    #   wall-budget rule (README "Testing strategy"): the shim unlocking
-    #   the whole mesh suite this round re-added its real runtime to
-    #   tier-1; the saturated-pipeline equivalence pin stays tier-1 and
-    #   the composition variants ride the slow tier (their single-device
-    #   twins in test_steady_fused remain tier-1 pins)
-    def test_full_turnover_across_laps_matches_single(self):
-        # write-only kernel: no aliasing, interpret-faithful across RING
-        # LAPS — CI pins the mesh turnover in the revisit regime directly
-        cfg = RaftConfig(n_replicas=3, entry_bytes=8, batch_size=B,
-                         log_capacity=256)
-        outs = self._run_both(cfg, [False] * 3, T=7)   # 896/256: 3.5 laps
-        assert_same(outs["mesh"], outs["single"], 3, 256)
-        assert int(outs["mesh"][1].commit_index) == 7 * B
-
-    @pytest.mark.slow   # wall-budget rule: see the first slow variant
-    def test_slow_follower_keeps_quorum(self):
-        cfg = RaftConfig(n_replicas=3, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        outs = self._run_both(cfg, [False, False, True], T=5,
-                              allow_turnover=False)
-        assert_same(outs["mesh"], outs["single"], 3, 5 * B)
-        assert int(outs["mesh"][1].commit_index) == 5 * B
-        assert int(np.asarray(outs["mesh"][0].last_index)[2]) == 0
-
-    @pytest.mark.slow   # wall-budget rule: see the first slow variant
-    def test_infeasible_degrades_to_scan_prefix(self):
-        cfg = RaftConfig(n_replicas=3, entry_bytes=8, batch_size=B,
-                         log_capacity=1024)
-        outs = self._run_both(cfg, [False, True, True], T=5)
-        assert_same(outs["mesh"], outs["single"], 3, 5 * B)
-        assert int(outs["mesh"][1].commit_index) == 0
-
-    @pytest.mark.slow   # wall-budget rule: see the first slow variant
-    def test_member_shrunk_pipeline(self):
-        # ADVICE r4 quorum semantics on the mesh path: member majority
-        # governs for non-EC, even below the initial majority
-        cfg = RaftConfig(n_replicas=3, entry_bytes=8, batch_size=B,
-                         log_capacity=1024, max_replicas=3)
-        member = jnp.asarray([True, False, False])
-        outs = self._run_both(cfg, [False] * 3, T=5, allow_turnover=False,
-                              member=member)
-        assert_same(outs["mesh"], outs["single"], 3, 5 * B)
-        assert int(outs["mesh"][1].commit_index) == 5 * B
-
-
 def test_mesh_fused_ec_shards():
     """EC on the mesh: pre-encoded shard windows ride the fused path;
     every row stores its own RS shard, byte-identical to the resident

@@ -122,12 +122,14 @@ class TestSingleChipKernels:
             commit_quorum=cfg.commit_quorum, interpret=False,
         ), "raft_step")
 
-    @pytest.mark.parametrize("cap,T,turnover", [
-        (1 << 20, 32, False),        # steady pipeline, T=32
-        (1 << 15, 32, True),         # turnover kernel: T*B == capacity
+    @pytest.mark.parametrize("cap,T", [
+        (1 << 20, 32),               # chip_smoke's north-star ring
+        (1 << 17, 128),              # one whole lap of a 2^17 ring
     ])
-    def test_pipeline(self, mosaic, one_chip, cap, T, turnover):
-        from raft_tpu.core.step_pallas import steady_pipeline_tpu
+    def test_lap_scan(self, mosaic, one_chip, cap, T):
+        """The fused scan ``submit_pipelined`` runs for a multi-batch
+        chunk, with its per-step infos stacked as the engine reads them."""
+        from raft_tpu.core.step_pallas import steady_scan_replicate_tpu
 
         cfg = RaftConfig(log_capacity=cap, **NS)
         wins = _sds((T, cfg.batch_size, cfg.rows * cfg.shard_words),
@@ -135,13 +137,13 @@ class TestSingleChipKernels:
         counts = _sds((T,), jnp.int32, one_chip)
         _, leader, term, vec, _, fpt, rf, _, tf = _step_args(cfg, one_chip)
         fn = jax.jit(functools.partial(
-            steady_pipeline_tpu, commit_quorum=cfg.commit_quorum,
-            interpret=False, allow_turnover=turnover,
-        ), donate_argnums=(0,))
+            steady_scan_replicate_tpu, commit_quorum=cfg.commit_quorum,
+            interpret=False,
+        ))
         _assert_kernel(fn.lower(
             _state(cfg, one_chip), wins, counts, leader, term, vec, vec,
             fpt, rf, None, tf,
-        ), "raft_turnover" if turnover else "raft_pipeline")
+        ), "raft_step")
 
     def test_ring_write_2e20(self, mosaic, one_chip):
         from raft_tpu.core.ring_pallas import write_window_cols_tpu
@@ -258,15 +260,17 @@ class TestMeshPrograms:
         _assert_kernel(prog.lower(state, win, c, leader, term, a, s, fpt,
                                   rf, tf))
 
-    def test_mesh_pipeline(self, mesh_t):
+    def test_mesh_lap_scan(self, mesh_t):
+        """One whole lap of the 2^15 ring as the repair-free fused scan
+        (``replicate_many``), the program a mesh chunk runs."""
         t = mesh_t
-        T = 32
+        T = t.cfg.log_capacity // t.cfg.batch_size
         W = t.cfg.rows * t.cfg.shard_words
         state, wins, _, leader, term, a, s, fpt, rf, tf = self._args(
             t, (T, t.cfg.batch_size, W))
         counts = _sds((T,), jnp.int32,
                       NamedSharding(t.mesh, jax.sharding.PartitionSpec()))
-        prog = t._fused_program("pipeline", True, True).__wrapped__
+        prog = t._fused_program("replicate_many", False).__wrapped__
         _assert_kernel(prog.lower(state, wins, counts, leader, term, a, s,
                                   fpt, rf, tf))
 
