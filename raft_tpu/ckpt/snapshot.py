@@ -211,6 +211,21 @@ class CheckpointStore:
         self.last = max(self.last, idx)
         self._sweep()
 
+    def put_run(self, lo: int, recs) -> None:
+        """Archive ``recs``, the ``(payload, term)`` tuples of the
+        contiguous range ``[lo, lo+len(recs))``, in one call: one slot
+        update, one ``last`` and one sweep for the run. Slots, ``last``
+        and the compaction floor end as the same records put one index
+        at a time leave them (the sweep pops up to the same floor either
+        way). The records land in ``_slots``, not in a span block, so a
+        read back of the run stays a dict hit."""
+        if not recs:
+            return
+        hi = lo + len(recs) - 1
+        self._slots.update(zip(range(lo, hi + 1), recs))
+        self.last = max(self.last, hi)
+        self._sweep()
+
     def put_span(self, lo: int, items, term: int,
                  pick: Optional[int] = None) -> None:
         """Archive the contiguous committed range ``[lo, lo+len(items))``

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import heapq
 import math
+import operator
 import os
 import random
 from typing import Callable, Dict, List, Optional, Tuple
@@ -64,6 +65,8 @@ from raft_tpu.transport.base import Transport, make_transport
 FOLLOWER = "follower"
 CANDIDATE = "candidate"
 LEADER = "leader"
+
+_second = operator.itemgetter(1)   # the term of a (payload, term) record
 
 
 class LinearizableReadRefused(Exception):
@@ -3067,7 +3070,20 @@ class RaftEngine:
         by construction. Under EC the device holds only shards, so missing
         entries are reconstructed from the leader + any k-1 live holders;
         if that fails the range is left unarchived (a later snapshot for it
-        is simply not offered)."""
+        is simply not offered).
+
+        The archive is a ``raft.archive`` span (``obs.profiling.phase``)
+        whose ``bulk`` stat counts the entries booked by one
+        ``CheckpointStore.put_run`` (0 when the range took the per-index
+        path)."""
+        with _profiling.phase("raft.archive", entries=hi - lo + 1) as span:
+            bulk = self._archive_range(leader, lo, hi)
+            if span is not None:
+                span.set_metadata(bulk=bulk)
+
+    def _archive_range(self, leader: int, lo: int, hi: int) -> int:
+        """``_archive_committed``'s work; returns the entries booked in
+        one ``put_run``."""
         from raft_tpu.core.state import log_entries
 
         # The buffer entry is only trustworthy if its ingest term matches
@@ -3080,11 +3096,23 @@ class RaftEngine:
         # whole-row fetch + numpy index (not jnp fancy indexing: that
         # compiles a fresh gather per slot-vector shape)
         lead_terms = self._fetch(self.state.log_term)[leader, slots_all]
-        missing = []
         aud = self.auditor
+        # The same guard over the whole range at once: when every index
+        # has a buffer record and every record's term is the leader's,
+        # the range is booked in one store call
+        recs = list(map(self._uncommitted.get, range(lo, hi + 1)))
+        if None not in recs and \
+                list(map(_second, recs)) == lead_terms.tolist():
+            self.store.put_run(lo, recs)
+            if aud is not None:
+                aud.note_entries(
+                    [(idx, p, t) for idx, (p, t) in enumerate(recs, lo)],
+                    self.clock.now,
+                )
+            return len(recs)
+        missing = []
         fed = [] if aud is not None else None
-        for i, idx in enumerate(range(lo, hi + 1)):
-            ent = self._uncommitted.get(idx)
+        for i, (idx, ent) in enumerate(zip(range(lo, hi + 1), recs)):
             if ent is not None and ent[1] == int(lead_terms[i]):
                 self.store.put(idx, ent[0], ent[1])
                 if fed is not None:
@@ -3097,7 +3125,7 @@ class RaftEngine:
             # already-recorded index is compared byte-for-byte
             aud.note_entries(fed, self.clock.now)
         if not missing:
-            return
+            return 0
         mlo, mhi = min(missing), max(missing)
         slots = (np.arange(mlo, mhi + 1) - 1) % self.state.capacity
         terms = self._fetch(self.state.log_term)[leader, slots]
@@ -3118,24 +3146,25 @@ class RaftEngine:
                     and self.connectivity[leader, q]
                 ]
                 if len(donors) < self.cfg.rs_k:
-                    return
+                    return 0
                 data = reconstruct(
                     self.state, self._code, donors[: self.cfg.rs_k], mlo, mhi
                 )
             else:
                 if int(self._ring_floor[leader]) > mlo:
-                    return  # ring never held the range; archive stays short
+                    return 0  # ring never held the range; archive stays short
                 data = log_entries(self.state, leader, mlo, mhi,
                                    fetch=self._fetch)
         except ValueError:
-            return
+            return 0
         for idx in missing:
             payload = data[idx - mlo].tobytes()
             self.store.put(idx, payload, int(terms[idx - mlo]))
-            if self.auditor is not None:
-                self.auditor.note_entry(
+            if aud is not None:
+                aud.note_entry(
                     idx, int(terms[idx - mlo]), payload, self.clock.now
                 )
+        return 0
 
     def _catchup_budget(self) -> int:
         """Chunks the catch-up lane may ship this tick: the admission

@@ -8,6 +8,7 @@ snapshot install + repair window.
 """
 
 import numpy as np
+import pytest
 
 from raft_tpu.config import RaftConfig
 from raft_tpu.ckpt import CheckpointStore, Snapshot, install_snapshot
@@ -121,6 +122,28 @@ class TestStore:
             s.put(i, bytes(ENTRY), 1)
         assert not s.covers(1, 20)
         assert s.covers(13, 20)
+
+    @pytest.mark.parametrize("max_entries", [None, 8],
+                             ids=["no_retention", "floor_crossed_mid_run"])
+    def test_put_run_equals_per_index_puts(self, max_entries):
+        """One range put leaves the slots, ``last``, the compaction floor
+        and every read as the same records put one index at a time; with
+        ``max_entries`` 8 the floor passes indices of the run itself."""
+        ps = payloads(40, seed=9)
+        recs = [(p, 1 + i // 7) for i, p in enumerate(ps)]
+        one = CheckpointStore(ENTRY, max_entries=max_entries)
+        run = CheckpointStore(ENTRY, max_entries=max_entries)
+        for lo, hi in ((1, 5), (6, 6), (7, 24), (25, 40)):
+            for i in range(lo, hi + 1):
+                one.put(i, *recs[i - 1])
+            run.put_run(lo, recs[lo - 1:hi])
+            assert run._slots == one._slots
+            assert (run.last, run.first) == (one.last, one.first) \
+                == (hi, 1 if max_entries is None else max(1, hi - 7))
+        run.put_run(41, [])                     # an empty run books nothing
+        assert (run.last, run._slots) == (one.last, one._slots)
+        assert [run.get(i) for i in range(42)] == \
+            [one.get(i) for i in range(42)]
 
 
 class TestSnapshotDisk:

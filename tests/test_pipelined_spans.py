@@ -17,6 +17,11 @@ Contracts under test:
 3. ``launch_annotation`` shows under a plain ``start_trace``.
 4. A commit that takes the stamp dict past its cap is a ``raft.evict``
    inside ``raft.commit``, its ``evicted`` stat the stamps dropped.
+5. Each commit's archive is one ``raft.archive`` inside ``raft.commit``:
+   ``entries`` the committed range, ``bulk`` the entries booked in one
+   ``CheckpointStore.put_run`` (all of them on a steady round, 0 when a
+   buffer record's term is not the leader's and the range takes the
+   per-index path, which archives the device's bytes for that index).
 """
 
 import jax
@@ -34,6 +39,7 @@ PLAIN = dict(n_replicas=3, entry_bytes=16)
 RS = dict(n_replicas=5, rs_k=3, rs_m=2, entry_bytes=24)
 PHASES = ["raft.gate", "raft.pack", "raft.dispatch", "raft.device_wait",
           "raft.account", "raft.commit"]
+INNER = ("raft.fetch", "raft.archive")   # spans nested inside a phase
 
 
 def mk_engine(**kw):
@@ -95,8 +101,13 @@ def test_spans_nest_and_count_upload_bytes(tmp_path, kind):
             stack = T * B * cfg.rows * cfg.shard_words * 4
         vectors = T * 4 + 2 * cfg.rows          # counts, alive, slow
         assert c.stats["padded"] == T * B
-        phases = [s for s in inside(c, spans) if s.name != "raft.fetch"]
+        phases = [s for s in inside(c, spans) if s.name not in INNER]
         assert [s.name for s in phases] == PHASES
+        # the chunk's whole commit is archived in one store call
+        (archive,) = [s for s in inside(c, spans) if s.name == "raft.archive"]
+        assert archive in inside(phases[-1], spans)
+        assert archive.stats == {"entries": c.stats["entries"],
+                                 "bulk": c.stats["entries"]}
         # every device read of the chunk lies inside one of its phases
         fetches = [s for s in inside(c, spans) if s.name == "raft.fetch"]
         assert fetches
@@ -111,14 +122,19 @@ def test_spans_nest_and_count_upload_bytes(tmp_path, kind):
         assert pack.stats["chips"] == 1
         assert pack.stats["bytes_per_chip"] == stack + vectors
     # self time: each chunk less its phases is the few statements
-    # between them; the phases hold no nested spans but device reads
+    # between them; the phases hold no nested spans but device reads and,
+    # in raft.commit, the archive (which holds its own read)
     for s, own in zip(spans, self_ns(spans)):
-        if s.name in PHASES:
-            reads = [f for f in inside(s, spans) if f.name == "raft.fetch"]
-            assert [f.name for f in inside(s, spans)] == [
-                "raft.fetch"] * len(reads)
+        if s.name in PHASES or s.name == "raft.archive":
+            nested = inside(s, spans)
+            archives = [a for a in nested if a.name == "raft.archive"]
+            assert len(archives) == (s.name == "raft.commit")
+            reads = [f for f in nested if f.name == "raft.fetch"
+                     and not any(f in inside(a, spans) for a in archives)]
+            assert len(reads) + len(archives) + sum(
+                len(inside(a, spans)) for a in archives) == len(nested)
             assert own == s.end_ns - s.start_ns - sum(
-                f.end_ns - f.start_ns for f in reads)
+                f.end_ns - f.start_ns for f in reads + archives)
         elif s.name == "raft.chunk":
             assert 0 <= own < (s.end_ns - s.start_ns) / 2
 
@@ -138,6 +154,32 @@ def test_evict_span_inside_commit_past_the_cap(tmp_path):
         == e.commit_stamps_evicted == 8 + 2 * cap - cap
     commits = [s for s in spans if s.name == "raft.commit"]
     assert all(any(s in inside(c, spans) for c in commits) for s in evicts)
+
+
+def test_stale_buffer_term_takes_the_per_index_archive(tmp_path):
+    """A buffer record whose term is not the leader's log term at its
+    index fails the range check: the round books entry by entry, and the
+    stale index is archived from the leader's device log, not the
+    buffer."""
+    e = mk_engine(**PLAIN)
+    cfg = e.cfg
+    e.submit_pipelined(payloads(cfg, 8, 12))         # compile outside
+    ps = payloads(cfg, 4, 13)
+    stale = e.commit_watermark + 2
+    archive = e._archive_range
+
+    def stale_record(leader, lo, hi):
+        p, t = e._uncommitted[stale]
+        e._uncommitted[stale] = (bytes(len(p)), t + 1)
+        return archive(leader, lo, hi)
+
+    e._archive_range = stale_record
+    spans = traced(tmp_path, lambda: e.submit_pipelined(ps))
+    (arc,) = [s for s in spans if s.name == "raft.archive"]
+    assert arc.stats == {"entries": 4, "bulk": 0}
+    assert e.commit_watermark == 12
+    term = int(e.lead_terms[e.leader_id])
+    assert [e.store.get(i) for i in range(9, 13)] == [(p, term) for p in ps]
 
 
 def test_no_session_builds_no_annotation(monkeypatch):

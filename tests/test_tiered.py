@@ -117,6 +117,35 @@ class TestTieredStore:
             np.frombuffer(b"".join(ps[:64]), np.uint8).reshape(64, ENTRY),
         )
 
+    def test_put_run_seals_what_per_index_puts_seal(self, tmp_path):
+        """Runs that cross segment boundaries, and one that seals four
+        segments at once, seal the same segments with the same shard
+        bytes as per-index puts, and leave the same hot tier."""
+        ps = blobs(100, seed=8)
+        recs = [(p, 1 + i // 30) for i, p in enumerate(ps)]
+
+        def store(sub):
+            return TieredStore(ENTRY, root=str(tmp_path / sub),
+                               hot_entries=16, segment_entries=8)
+
+        one, run = store("one"), store("run")
+        for lo, hi in ((1, 13), (14, 29), (30, 30), (31, 58), (59, 100)):
+            for i in range(lo, hi + 1):
+                one.put(i, *recs[i - 1])
+            run.put_run(lo, recs[lo - 1:hi])
+            assert run._sealed == one._sealed
+        assert run.stats == one.stats
+        assert len(run._sealed) == (100 - 16) // 8
+        assert (run._slots, run.last, run._hot_first) == \
+            (one._slots, one.last, one._hot_first)
+        for lo, hi in run._sealed:
+            for r in range(run.io.code.n):
+                name = run.io.name(lo, hi)
+                with open(run.io.shard_path(name, r), "rb") as a, \
+                        open(one.io.shard_path(name, r), "rb") as b:
+                    assert a.read() == b.read()
+        assert [run.get(i) for i in range(1, 101)] == recs
+
     def test_apply_cursor_caps_sealing(self, tmp_path):
         s = TieredStore(
             ENTRY, root=str(tmp_path), hot_entries=16, segment_entries=8

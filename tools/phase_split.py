@@ -24,7 +24,11 @@ result line with one more key, ``phases``:
   the engine makes, its time counted in the phase around it as well),
   ``evict_us_per_entry`` and ``evicted_per_chunk`` (the ``raft.evict``
   spans and their ``evicted`` stat: the commit-stamp eviction, its time
-  counted in ``raft.commit`` as well) and ``collective_us_per_entry``
+  counted in ``raft.commit`` as well), ``archive_us_per_entry`` and
+  ``archive_bulk_share`` (the ``raft.archive`` spans, and their ``bulk``
+  stat over their ``entries``: the entries booked into the checkpoint
+  store in one range put; time counted in ``raft.commit`` as well; None
+  when the program has no such span) and ``collective_us_per_entry``
   (the benchmark's reader of that name: the leader chip's collective
   ops);
 - ``coverage``: the share of each ``raft.chunk`` its phases cover, of
@@ -55,8 +59,9 @@ ROOT = Path(__file__).resolve().parent.parent
 
 FETCH = "raft.fetch"
 EVICT = "raft.evict"
+ARCHIVE = "raft.archive"
 #: spans timed inside the phase around them, as before they had a span
-INNER = (FETCH, EVICT)
+INNER = (FETCH, EVICT, ARCHIVE)
 CHUNK_PHASES = ("raft.gate", "raft.pack", "raft.dispatch",
                 "raft.device_wait", "raft.account", "raft.commit")
 
@@ -74,6 +79,7 @@ def split(run, spans) -> dict:
     inner = [s for s in spans if s.name in INNER and lo <= s.start_ns < hi]
     reads = [s for s in inner if s.name == FETCH]
     evicts = [s for s in inner if s.name == EVICT]
+    archives = [s for s in inner if s.name == ARCHIVE]
     spans = [s for s in spans if s.name not in INNER]
     own = self_ns(spans)
     keep = [(s, o) for s, o in zip(spans, own) if lo <= s.start_ns < hi]
@@ -112,6 +118,12 @@ def split(run, spans) -> dict:
         sum(s.end_ns - s.start_ns for s in evicts))
     readings["evicted_per_chunk"] = sum(
         s.stats.get("evicted", 0) for s in evicts) / len(chunks)
+    archived = sum(s.stats.get("entries", 0) for s in archives)
+    readings["archive_us_per_entry"] = per_entry(
+        sum(s.end_ns - s.start_ns for s in archives)) if archives else None
+    readings["archive_bulk_share"] = (sum(
+        s.stats.get("bulk", 0) for s in archives) / archived
+        if archived else None)
     readings["collective_us_per_entry"] = load_reader(
         run.root, "collective_us_per_entry")(run)
     padded = sum(c.get("padded", 0) for c in chunks)
